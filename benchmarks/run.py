@@ -35,6 +35,9 @@ def main() -> None:
         ap.error("--full and --smoke are mutually exclusive")
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         availability,
         batched_read,

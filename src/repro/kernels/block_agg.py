@@ -42,11 +42,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .scan_agg import _pad_to
 
-__all__ = ["block_sums", "block_sums_kernel", "boundary_block_sums"]
+__all__ = [
+    "block_sums",
+    "block_sums_kernel",
+    "boundary_block_kernel",
+    "boundary_block_sums",
+]
 
 
 def block_sums_kernel(vals_ref, out_ref):
@@ -101,16 +108,47 @@ def block_sums(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("block_n",))
-def _boundary_call(values, sel, blocks, win_lo, win_hi, *, block_n):
-    bn = jnp.arange(block_n, dtype=jnp.int32)[None, :]
-    cols = blocks[:, None] * block_n + bn  # (P, block_n) global row idx
-    vals = values[sel[:, None], cols]  # (P, block_n) each pair's value row
-    inw = (cols[:, None, :] >= win_lo[:, :, None]) & (
-        cols[:, None, :] < win_hi[:, :, None]
+def boundary_block_kernel(n_win, blocks_ref, vals_ref, win_ref, out_ref):
+    """One (query, block) pair per grid step: the pair's row block of
+    the value tile (chosen by the scalar-prefetched block index) times
+    its window-union mask, reduced per value row exactly as the fused
+    kernel reduces ``vq * fmask``. Lane 0 of each output row carries
+    that value row's partial."""
+    p = pl.program_id(0)
+    block_n = vals_ref.shape[1]
+    cols = blocks_ref[p] * block_n + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_n), 1
     )
-    fmask = jnp.any(inw, axis=1).astype(jnp.float32)  # (P, block_n)
-    return jnp.sum(vals * fmask, axis=1)
+    win = win_ref[0]  # (8, W_pad): sublane 0 window starts, 1 stops
+    inw = jnp.zeros((1, block_n), jnp.bool_)
+    for w in range(n_win):
+        inw |= (cols >= win[0:1, w : w + 1]) & (cols < win[1:2, w : w + 1])
+    part = jnp.sum(vals_ref[...] * inw.astype(jnp.float32), axis=1, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    out_ref[...] = jnp.where(lane == 0, part, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("n_win", "block_n", "interpret"))
+def _boundary_call(values, sel, blocks, win, *, n_win, block_n, interpret):
+    V, N = values.shape
+    V_pad = max(8, -(-V // 8) * 8)
+    vals_p = _pad_to(values.astype(jnp.float32), V_pad, 0, 0.0)
+    P = blocks.shape[0]
+    out = pl.pallas_call(
+        functools.partial(boundary_block_kernel, n_win),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(P,),
+            in_specs=[
+                pl.BlockSpec((V_pad, block_n), lambda p, blk: (0, blk[p])),
+                pl.BlockSpec((1,) + win.shape[1:], lambda p, blk: (p, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((V_pad, 128), lambda p, blk: (p, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((P * V_pad, 128), jnp.float32),
+        interpret=interpret,
+    )(blocks, vals_p, win)
+    return out.reshape(P, V_pad, 128)[jnp.arange(P), sel, 0]
 
 
 def boundary_block_sums(
@@ -121,25 +159,31 @@ def boundary_block_sums(
     win_hi,  # int[P, W] window stops (global row idx, exclusive)
     *,
     block_n: int,
-) -> jax.Array:
+    interpret: bool | None = None,
+) -> np.ndarray:
     """float32[P] masked partial sums of boundary blocks: pair ``p``
     gets ``sum(values[sel[p], rows of block blocks[p] inside any
     [win_lo[p, w], win_hi[p, w]) window])`` — the fused kernel's
-    per-block ``jnp.sum(vq * fmask, axis=1)`` restricted to one block
-    (same ``(pairs-padded-to-8, block_n)`` reduction shape). Empty
-    window slots are encoded ``lo >= hi``."""
-    sel = jnp.asarray(sel, jnp.int32)
-    blocks = jnp.asarray(blocks, jnp.int32)
-    win_lo = jnp.asarray(win_lo, jnp.int32)
-    win_hi = jnp.asarray(win_hi, jnp.int32)
-    P = int(sel.shape[0])
-    P_pad = max(8, -(-P // 8) * 8)
-    sel = _pad_to(sel[:, None], P_pad, 0, 0)[:, 0]
-    blocks = _pad_to(blocks[:, None], P_pad, 0, 0)[:, 0]
-    win_lo = _pad_to(win_lo, P_pad, 0, 0)
-    win_hi = _pad_to(win_hi, P_pad, 0, 0)  # pad pairs: lo == hi == 0 → empty
+    per-block ``jnp.sum(vq * fmask, axis=1)`` restricted to one block,
+    computed by a Pallas kernel with the same ``(rows, block_n)``
+    reduction so the bits match on every backend. Empty window slots
+    are encoded ``lo >= hi``; pairs are padded to a power of two (empty
+    windows) so a cold run compiles few programs."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    sel = np.asarray(sel, np.int32)
+    win_lo = np.asarray(win_lo, np.int32)
+    win_hi = np.asarray(win_hi, np.int32)
+    P, n_win = win_lo.shape
+    P_pad = max(8, 1 << max(P - 1, 0).bit_length())
+    W_pad = max(128, -(-n_win // 128) * 128)
+    win = np.zeros((P_pad, 8, W_pad), np.int32)  # pad pairs: lo == hi == 0
+    win[:P, 0, :n_win] = win_lo
+    win[:P, 1, :n_win] = win_hi
     out = _boundary_call(
-        jnp.asarray(values, jnp.float32), sel, blocks, win_lo, win_hi,
-        block_n=block_n,
+        jnp.asarray(values, jnp.float32),
+        np.pad(sel, (0, P_pad - P)),
+        np.pad(np.asarray(blocks, np.int32), (0, P_pad - P)),
+        win, n_win=n_win, block_n=block_n, interpret=interpret,
     )
-    return out[:P]
+    return np.asarray(out)[:P]

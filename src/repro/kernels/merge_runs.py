@@ -26,11 +26,11 @@ existing rows — so the computed permutation equals the incremental
 ``row_map`` and the compacted device order equals the host row order
 (``row_map`` collapses to identity; property-tested).
 
-Work is O(N_base · M + M · N) popcounts for M appended rows (the base
-probes only stream the appended suffix: their strict window is empty
-and their inclusive window starts at the base boundary, so the grid is
-launched from that block onward). The numpy/lexsort oracle lives in
-``ref.merge_run_positions_ref``.
+Work is O(M · N) popcounts for M appended rows: only appended rows are
+probed, and the base rows' positions follow from the appended rows'
+strict ranks in the sorted base (``merge_run_positions``), so the
+N-sized base never becomes a probe set. The numpy/lexsort oracle lives
+in ``ref.merge_run_positions_ref``.
 """
 
 from __future__ import annotations
@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .scan_agg import _pad_to
+from .scan_agg import _pad_to, launch_query_chunks, query_chunk
 from .slab_locate import _lex_tuple_ge, _lex_tuple_le
 
 __all__ = [
@@ -134,41 +134,32 @@ def merge_rank_batched(
     n_lanes: int,
     row_start: int = 0,
     block_n: int = 2048,
-    max_q: int = 1024,
+    max_q: int | None = None,
     interpret: bool | None = None,
-) -> jax.Array:
+) -> np.ndarray:
     """int32[Q, 2] = per probe, (strict rank in its lt window, inclusive
     rank in its le window). ``row_start`` drops whole leading row blocks
-    from the stream when every window lies at or past it."""
+    from the stream when every window lies at or past it. Launches
+    carry at most :func:`query_chunk` probes (lowered by ``max_q``)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     keys = jnp.asarray(keys, jnp.int32)
-    probes = jnp.asarray(probes, jnp.int32)
-    lim_lt = jnp.asarray(lim_lt, jnp.int32)
-    lim_le = jnp.asarray(lim_le, jnp.int32)
+    probes = np.asarray(probes, np.int32)
     if not 0 < n_lanes <= keys.shape[0]:
         raise ValueError(f"n_lanes {n_lanes} out of range for {keys.shape[0]} key lanes")
     if probes.shape[1] < n_lanes:
         raise ValueError(f"probes carry {probes.shape[1]} lanes, need {n_lanes}")
-    row_off = row_start // block_n
-    call = functools.partial(
-        _merge_rank_call,
-        keys,
-        n_lanes=n_lanes,
-        row_off=row_off,
-        block_n=block_n,
-        interpret=interpret,
+    (out,) = launch_query_chunks(
+        lambda *ops: (
+            _merge_rank_call(
+                keys, *ops, n_lanes=n_lanes, row_off=row_start // block_n,
+                block_n=block_n, interpret=interpret,
+            ),
+        ),
+        (probes, lim_lt, lim_le),
+        max_q=query_chunk(block_n, max_q),
     )
-    Q = probes.shape[0]
-    if Q <= max_q:
-        return call(probes, lim_lt, lim_le)
-    return jnp.concatenate(
-        [
-            call(probes[s : s + max_q], lim_lt[s : s + max_q], lim_le[s : s + max_q])
-            for s in range(0, Q, max_q)
-        ],
-        axis=0,
-    )
+    return out
 
 
 def merge_run_positions(
@@ -183,9 +174,13 @@ def merge_run_positions(
 ) -> np.ndarray:
     """int64[n_rows] merged position of every device row — the k-way
     merge permutation (see module docstring for the tie rule). Two rank
-    launches: one for the appended rows (strict prefix + inclusive
-    suffix windows), one for the base rows (inclusive window over the
-    appended suffix only, streamed from the base boundary onward)."""
+    launches, both probed by the appended rows only: one over every row
+    (strict rank in the base, inclusive rank in the later runs), one
+    streamed from the base boundary onward (strict rank in the earlier
+    appended runs). Base rows need no probe: the base is sorted, so an
+    appended row with strict base rank ``lb`` precedes exactly the base
+    rows ``i >= lb``, and base row ``i`` moves down by the number of
+    appended rows with ``lb <= i``."""
     starts = np.asarray(tuple(run_starts) + (n_rows,), dtype=np.int64)
     n_runs = len(starts) - 1
     if n_runs <= 1:
@@ -197,38 +192,39 @@ def merge_run_positions(
     base_end = int(starts[1])
     m = n_rows - base_end
     run_lens = np.diff(starts)[1:]  # appended runs only
+    run_start = np.repeat(starts[1:-1], run_lens)  # each probe's run start
 
-    # appended probes: strict rank over their predecessors [0, start_r),
-    # inclusive rank over their successors [end_r, n_rows)
-    probes_app = jnp.asarray(keys)[:n_lanes, base_end:n_rows].T
-    lim_lt = np.zeros((m, 2), np.int64)
-    lim_lt[:, 1] = np.repeat(starts[1:-1], run_lens)
-    lim_le = np.empty((m, 2), np.int64)
-    lim_le[:, 0] = np.repeat(starts[2:], run_lens)
-    lim_le[:, 1] = n_rows
-    ranks_app = np.asarray(
+    # launch 1: strict rank in the base [0, base_end), inclusive rank in
+    # the successor runs [end_r, n_rows)
+    probes = np.asarray(keys[:n_lanes, base_end:n_rows]).T
+    lim_base = np.zeros((m, 2), np.int64)
+    lim_base[:, 1] = base_end
+    lim_after = np.empty((m, 2), np.int64)
+    lim_after[:, 0] = np.repeat(starts[2:], run_lens)
+    lim_after[:, 1] = n_rows
+    ranks = np.asarray(
         merge_rank_batched(
-            keys, probes_app, lim_lt, lim_le, n_lanes=n_lanes, block_n=block_n,
+            keys, probes, lim_base, lim_after, n_lanes=n_lanes, block_n=block_n,
             interpret=interpret,
         ),
         np.int64,
     )
-    local = np.arange(m, dtype=np.int64) - np.repeat(starts[1:-1] - base_end, run_lens)
-    pos_app = local + ranks_app[:, 0] + ranks_app[:, 1]
-
-    # base probes: inclusive rank over the appended suffix only — the
-    # grid starts at the base boundary's block, skipping the base rows
-    probes_base = jnp.asarray(keys)[:n_lanes, :base_end].T
-    zeros = np.zeros((base_end, 2), np.int64)
-    lim_le_b = np.empty((base_end, 2), np.int64)
-    lim_le_b[:, 0] = base_end
-    lim_le_b[:, 1] = n_rows
-    ranks_base = np.asarray(
+    lb = ranks[:, 0]
+    # launch 2: strict rank in the earlier appended runs [base_end,
+    # start_r), streamed from the base boundary's block onward
+    lim_before = np.empty((m, 2), np.int64)
+    lim_before[:, 0] = base_end
+    lim_before[:, 1] = run_start
+    before = np.asarray(
         merge_rank_batched(
-            keys, probes_base, zeros, lim_le_b, n_lanes=n_lanes,
-            row_start=base_end, block_n=block_n, interpret=interpret,
+            keys, probes, lim_before, np.zeros((m, 2), np.int64),
+            n_lanes=n_lanes, row_start=base_end, block_n=block_n,
+            interpret=interpret,
         ),
         np.int64,
-    )
-    pos_base = np.arange(base_end, dtype=np.int64) + ranks_base[:, 1]
+    )[:, 0]
+    local = np.arange(m, dtype=np.int64) - (run_start - base_end)
+    pos_app = local + lb + before + ranks[:, 1]
+    base_rows = np.arange(base_end, dtype=np.int64)
+    pos_base = base_rows + np.searchsorted(np.sort(lb), base_rows, side="right")
     return np.concatenate([pos_base, pos_app])

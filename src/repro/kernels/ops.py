@@ -100,8 +100,11 @@ def scan_agg(keys, values, col_lo, col_hi, slab, *, block_n: int = 2048, use_pal
 
 
 def ecdf_hist(col, *, n_bins: int, bin_width: int, block_n: int = 512, use_pallas: bool = True):
+    """float32[n_bins] bin counts. The kernel path takes at most 4096
+    bins and raises beyond that — callers with wider tables bin on the
+    host themselves (``ColumnStats.merge_values``)."""
     col = jnp.asarray(col, jnp.int32)
-    if not use_pallas or n_bins > 4096:
+    if not use_pallas:
         return ref.ecdf_hist_ref(col, n_bins=n_bins, bin_width=bin_width)
     return ecdf_hist_pallas(col, n_bins=n_bins, bin_width=bin_width, block_n=block_n)
 

@@ -56,6 +56,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 __all__ = [
@@ -149,6 +150,47 @@ def _pad_to(x: jax.Array, size: int, axis: int, fill) -> jax.Array:
     return jnp.pad(x, widths, constant_values=fill)
 
 
+# VMEM budget of the row-streaming kernels: each grid step holds several
+# (Q_pad, block_n) predicate/mask intermediates, so queries per launch
+# scale inversely with the row block. Compiled for TPU v5e at 8 key
+# lanes, the fused locate+scan kernel fits 128 queries at block_n = 8192
+# and runs out of VMEM at 256; Q_pad * block_n <= 2**20 keeps every
+# row-streaming kernel inside that bound.
+QUERY_BLOCK_ELEMS = 1 << 20
+
+
+def query_chunk(block_n: int, max_q: int | None = None) -> int:
+    """Most queries one launch of a ``(Q_pad, block_n)`` row-streaming
+    kernel may carry: the VMEM budget, optionally lowered by ``max_q``
+    (a multiple of 8, at least 8)."""
+    cap = max(8, QUERY_BLOCK_ELEMS // block_n // 8 * 8)
+    if max_q is not None:
+        cap = max(8, min(cap, -(-max_q // 8) * 8))
+    return cap
+
+
+def launch_query_chunks(call, operands, *, max_q: int) -> tuple[np.ndarray, ...]:
+    """Run ``call`` over the query axis (axis 0 of every operand) in
+    launches of at most ``max_q`` queries. Each launch is zero-padded on
+    the host to a power-of-two query count (at least 8), so a cold run
+    compiles a handful of programs, not one per group size; zero rows
+    carry an empty ``(0, 0)`` row window and count nothing. Returns the
+    outputs of ``call`` (a tuple of arrays with a leading query axis)
+    sliced back and concatenated on the host."""
+    operands = [np.asarray(x) for x in operands]
+    n_q = operands[0].shape[0]
+    parts = []
+    for s in range(0, max(n_q, 1), max_q):
+        n = min(max_q, n_q - s)
+        bucket = min(max_q, max(8, 1 << max(n - 1, 0).bit_length()))
+        chunk = [
+            np.pad(x[s : s + n], [(0, bucket - n)] + [(0, 0)] * (x.ndim - 1))
+            for x in operands
+        ]
+        parts.append([np.asarray(o)[:n] for o in call(*chunk)])
+    return tuple(np.concatenate(outs, axis=0) for outs in zip(*parts))
+
+
 @functools.partial(
     jax.jit, static_argnames=("col_parts", "n_vals", "block_n", "interpret")
 )
@@ -215,16 +257,16 @@ def scan_agg_batched_pallas(
     col_parts: tuple[int, ...] | None = None,
     n_vals: int | None = None,
     block_n: int = 2048,
-    max_q: int = 1024,
+    max_q: int | None = None,
     interpret: bool | None = None,
-) -> jax.Array:
+) -> np.ndarray:
     """Returns float32[Q, 2]: per query, (masked sum of values, count).
 
     One row-streaming launch serves the whole batch (see module
-    docstring); batches larger than ``max_q`` are chunked so the
-    resident accumulator/bounds blocks stay within VMEM — each chunk
-    still streams the columns exactly once. ``keys``/``values`` may
-    carry pre-padded sublane rows beyond the ``col_parts`` lanes /
+    docstring); batches larger than :func:`query_chunk` (lowered by
+    ``max_q``) are chunked so the per-step blocks stay within VMEM —
+    each chunk still streams the columns exactly once. ``keys``/``values``
+    may carry pre-padded sublane rows beyond the ``col_parts`` lanes /
     ``n_vals`` live value rows (the device-resident layout); padded rows
     are never referenced.
     """
@@ -234,14 +276,14 @@ def scan_agg_batched_pallas(
     if values.ndim == 1:
         values = values[None, :]
     keys = jnp.asarray(keys, jnp.int32)
-    col_lo = jnp.asarray(col_lo, jnp.int32)
-    col_hi = jnp.asarray(col_hi, jnp.int32)
-    slabs = jnp.asarray(slabs, jnp.int32)
+    col_lo = np.asarray(col_lo, np.int32)
+    col_hi = np.asarray(col_hi, np.int32)
+    slabs = np.asarray(slabs, np.int32)
     Q, K_ex = col_lo.shape
     if value_sel is None:
-        value_sel = jnp.zeros(Q, jnp.int32)
+        value_sel = np.zeros(Q, np.int32)
     else:
-        value_sel = jnp.asarray(value_sel, jnp.int32)
+        value_sel = np.asarray(value_sel, np.int32)
     if col_parts is None:
         col_parts = (1,) * K_ex
     col_parts = tuple(int(p) for p in col_parts)
@@ -255,22 +297,17 @@ def scan_agg_batched_pallas(
         n_vals = int(values.shape[0])
     if not 0 < n_vals <= values.shape[0]:
         raise ValueError(f"n_vals {n_vals} out of range for {values.shape[0]} rows")
-    if Q <= max_q:
-        return _rowstream_call(
-            keys, values, col_lo, col_hi, slabs, value_sel,
-            col_parts=col_parts, n_vals=n_vals, block_n=block_n,
-            interpret=interpret,
-        )
-    chunks = [
-        _rowstream_call(
-            keys, values, col_lo[s : s + max_q], col_hi[s : s + max_q],
-            slabs[s : s + max_q], value_sel[s : s + max_q],
-            col_parts=col_parts, n_vals=n_vals, block_n=block_n,
-            interpret=interpret,
-        )
-        for s in range(0, Q, max_q)
-    ]
-    return jnp.concatenate(chunks, axis=0)
+    (out,) = launch_query_chunks(
+        lambda *ops: (
+            _rowstream_call(
+                keys, values, *ops, col_parts=col_parts, n_vals=n_vals,
+                block_n=block_n, interpret=interpret,
+            ),
+        ),
+        (col_lo, col_hi, slabs, value_sel),
+        max_q=query_chunk(block_n, max_q),
+    )
+    return out
 
 
 # -- legacy queries-outer grid (kept for the perf trajectory bench) ----------

@@ -42,11 +42,13 @@ remove those host hops.
     prefix-sum compaction. Two passes: the fused kernel counts matches
     (sizing the output), then this kernel walks the row blocks keeping a
     per-query running base in a VMEM-resident carry accumulator; each
-    block computes an exclusive prefix sum of its match mask and
-    scatters row indices into ``base + local`` of a pre-sized
-    ``(Q, out_width)`` output. The scatter is windowed (one writer per
-    slot, masked lanes contribute +0), exact in interpret mode; a Mosaic
-    lowering would swap it for the one-hot matmul form.
+    128-row sub-chunk computes an exclusive prefix sum of its match mask
+    (a triangular-ones matmul) and places row indices at ``base +
+    local`` of a pre-sized ``(Q, out_width)`` output in one-hot form —
+    no cumsum and no dynamic scatter, both of which Mosaic refuses.
+
+Every wrapper launches at most :func:`query_chunk` queries at a time,
+the VMEM bound of the ``(Q_pad, block_n)`` per-step intermediates.
 
 Lane layout, ``col_parts`` (wide two-lane columns) and padding
 conventions are shared with ``scan_agg`` — lexicographic comparison
@@ -59,9 +61,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-from .scan_agg import _lex_ge, _lex_lt, _pad_to
+from .scan_agg import _lex_ge, _lex_lt, _pad_to, launch_query_chunks, query_chunk
 
 __all__ = [
     "slab_locate_kernel",
@@ -216,37 +219,34 @@ def slab_locate_batched(
     *,
     n_lanes: int | None = None,
     block_n: int = 2048,
-    max_q: int = 1024,
+    max_q: int | None = None,
     interpret: bool | None = None,
-) -> jax.Array:
+) -> np.ndarray:
     """int32[Q, 2] = (lo_idx, hi_idx) row slabs — the vectorized binary
     search. On a sorted key column this equals ``searchsorted(packed,
     lo, "left")`` / ``searchsorted(packed, hi, "right")``. An empty
     query is encoded as ``slab_lo = 0``-lanes, ``slab_hi = -1``-lanes
-    (or a ``(0, 0)`` window) and yields ``(0, 0)``."""
+    (or a ``(0, 0)`` window) and yields ``(0, 0)``. Launches carry at
+    most :func:`query_chunk` queries (lowered by ``max_q``)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     keys = jnp.asarray(keys, jnp.int32)
-    slab_lo = jnp.asarray(slab_lo, jnp.int32)
-    slab_hi = jnp.asarray(slab_hi, jnp.int32)
-    limits = jnp.asarray(limits, jnp.int32)
-    Q, K_ex = slab_lo.shape
+    slab_lo = np.asarray(slab_lo, np.int32)
+    K_ex = slab_lo.shape[1]
     if n_lanes is None:
         n_lanes = K_ex
     if not 0 < n_lanes <= keys.shape[0]:
         raise ValueError(f"n_lanes {n_lanes} out of range for {keys.shape[0]} key lanes")
-    call = functools.partial(
-        _slab_locate_call, keys, n_lanes=n_lanes, block_n=block_n, interpret=interpret
+    (out,) = launch_query_chunks(
+        lambda *ops: (
+            _slab_locate_call(
+                keys, *ops, n_lanes=n_lanes, block_n=block_n, interpret=interpret
+            ),
+        ),
+        (slab_lo, np.asarray(slab_hi, np.int32), np.asarray(limits, np.int32)),
+        max_q=query_chunk(block_n, max_q),
     )
-    if Q <= max_q:
-        return call(slab_lo, slab_hi, limits)
-    return jnp.concatenate(
-        [
-            call(slab_lo[s : s + max_q], slab_hi[s : s + max_q], limits[s : s + max_q])
-            for s in range(0, Q, max_q)
-        ],
-        axis=0,
-    )
+    return out
 
 
 # -- fused locate + scan ------------------------------------------------------
@@ -382,31 +382,26 @@ def scan_agg_locate_batched(
     col_parts: tuple[int, ...] | None = None,
     n_vals: int | None = None,
     block_n: int = 2048,
-    max_q: int = 1024,
+    max_q: int | None = None,
     interpret: bool | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Fused locate+scan: ``(sum f32[Q], matched i32[Q], slab_rows
-    i32[Q])`` in one launch, columns streamed from HBM once per batch.
-    ``slab_rows`` is the number of rows a sorted scan of the slab would
-    stream (== ``hi_idx - lo_idx`` of :func:`slab_locate_batched`);
-    matched/sum use the residual per-column predicate only, which the
-    slab provably contains."""
+    i32[Q])``, columns streamed from HBM once per launch of at most
+    :func:`query_chunk` queries (lowered by ``max_q``). ``slab_rows``
+    is the number of rows a sorted scan of the slab would stream (==
+    ``hi_idx - lo_idx`` of :func:`slab_locate_batched`); matched/sum
+    use the residual per-column predicate only, which the slab provably
+    contains."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     values = jnp.asarray(values, jnp.float32)
     if values.ndim == 1:
         values = values[None, :]
     keys = jnp.asarray(keys, jnp.int32)
-    res_lo = jnp.asarray(res_lo, jnp.int32)
-    res_hi = jnp.asarray(res_hi, jnp.int32)
-    slab_lo = jnp.asarray(slab_lo, jnp.int32)
-    slab_hi = jnp.asarray(slab_hi, jnp.int32)
-    limits = jnp.asarray(limits, jnp.int32)
+    res_lo = np.asarray(res_lo, np.int32)
     Q, K_ex = res_lo.shape
     if value_sel is None:
-        value_sel = jnp.zeros(Q, jnp.int32)
-    else:
-        value_sel = jnp.asarray(value_sel, jnp.int32)
+        value_sel = np.zeros(Q, np.int32)
     if col_parts is None:
         col_parts = (1,) * K_ex
     col_parts = tuple(int(p) for p in col_parts)
@@ -418,32 +413,26 @@ def scan_agg_locate_batched(
         n_vals = int(values.shape[0])
     if not 0 < n_vals <= values.shape[0]:
         raise ValueError(f"n_vals {n_vals} out of range for {values.shape[0]} rows")
-    call = functools.partial(
-        _fused_call,
-        keys,
-        values,
-        col_parts=col_parts,
-        n_vals=n_vals,
-        block_n=block_n,
-        interpret=interpret,
+    return launch_query_chunks(
+        functools.partial(
+            _fused_call,
+            keys,
+            values,
+            col_parts=col_parts,
+            n_vals=n_vals,
+            block_n=block_n,
+            interpret=interpret,
+        ),
+        (res_lo, res_hi, slab_lo, slab_hi, limits, value_sel),
+        max_q=query_chunk(block_n, max_q),
     )
-    if Q <= max_q:
-        return call(res_lo, res_hi, slab_lo, slab_hi, limits, value_sel)
-    parts = [
-        call(
-            res_lo[s : s + max_q],
-            res_hi[s : s + max_q],
-            slab_lo[s : s + max_q],
-            slab_hi[s : s + max_q],
-            limits[s : s + max_q],
-            value_sel[s : s + max_q],
-        )
-        for s in range(0, Q, max_q)
-    ]
-    return tuple(jnp.concatenate([p[j] for p in parts], axis=0) for j in range(3))
 
 
 # -- "select": block-local prefix-sum compaction ------------------------------
+
+# rows per select sub-chunk, and output slots per scatter tile: one lane
+# width, so the prefix-sum matmul is (Q, 128) x (128, 128)
+_SUB = 128
 
 
 def select_compact_kernel(
@@ -452,9 +441,16 @@ def select_compact_kernel(
     """One row-block step of the two-pass select: the carry accumulator
     (lane 0) holds each query's match count over earlier blocks; this
     block's matches land at ``carry + exclusive-prefix-sum`` of the
-    match mask. The scatter is windowed — every matched row owns its
-    output slot, masked lanes add 0 — so the result is exact regardless
-    of duplicate clamped positions."""
+    match mask.
+
+    The block is walked in 128-row sub-chunks. A sub-chunk's exclusive
+    prefix sum is one MXU matmul of its 0/1 match mask against a
+    strictly upper-triangular ones matrix (exact: 0/1 operands, sums of
+    at most 128). The scatter is its one-hot form: for each 128-slot
+    output tile the sub-chunk can reach, ``(pos == slot) & matched``
+    selects each matched row's index into its slot, summed over rows —
+    every matched row owns its slot, so the sum is exact. Sub-chunks
+    without matches skip the scatter."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -462,23 +458,49 @@ def select_compact_kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    keys = keys_ref[...]
-    ridx, valid = _row_window(limits_ref[...], keys.shape[1], i)
-    matched = _residual_pred(keys, res_lo_ref[...], res_hi_ref[...], col_parts, valid)
+    block_n = keys_ref.shape[1]
+    n_tiles = out_ref.shape[1] // _SUB
+    limits = limits_ref[...]
+    res_lo = res_lo_ref[...]
+    res_hi = res_hi_ref[...]
+    tri = (
+        jax.lax.broadcasted_iota(jnp.int32, (_SUB, _SUB), 0)
+        < jax.lax.broadcasted_iota(jnp.int32, (_SUB, _SUB), 1)
+    ).astype(jnp.float32)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _SUB), 2)
 
-    m = matched.astype(jnp.int32)  # (Q, block_n)
-    local = jnp.cumsum(m, axis=1) - m  # exclusive prefix sum per query
-    base = carry_ref[:, 0:1]
-    width = out_ref.shape[1]
-    # clamp keeps masked positions in range; their contribution is +0
-    pos = jnp.minimum(base + local, width - 1)
-    qidx = jax.lax.broadcasted_iota(jnp.int32, m.shape, 0)
-    rmat = jnp.broadcast_to(ridx, m.shape)
-    out_ref[...] = out_ref[...].at[qidx, pos].add(jnp.where(matched, rmat, 0))
+    def sub_chunk(c, base):  # base: (Q, 1) matches before this sub-chunk
+        start = pl.multiple_of(c * _SUB, _SUB)
+        keys = keys_ref[:, pl.ds(start, _SUB)]
+        ridx = i * block_n + start + jax.lax.broadcasted_iota(jnp.int32, (1, _SUB), 1)
+        valid = (ridx >= limits[:, 0:1]) & (ridx < limits[:, 1:2])
+        matched = _residual_pred(keys, res_lo, res_hi, col_parts, valid)
+        cnt = jnp.sum(matched.astype(jnp.int32), axis=1, keepdims=True)
+
+        @pl.when(jnp.max(cnt) > 0)
+        def _scatter():
+            local = jnp.dot(
+                matched.astype(jnp.float32), tri, preferred_element_type=jnp.float32
+            ).astype(jnp.int32)
+            pos = jnp.where(matched, base + local, -1)
+            # output tiles this sub-chunk's matches reach, over all queries
+            t_lo = jnp.min(jnp.where(cnt > 0, base, 1 << 30)) // _SUB
+            t_hi = jnp.max(jnp.where(cnt > 0, base + cnt - 1, -1)) // _SUB
+            rows = ridx[:, :, None]
+
+            def tile(t, carry):
+                s0 = pl.multiple_of(t * _SUB, _SUB)
+                hit = pos[:, :, None] == s0 + slot  # (Q, rows, slots)
+                out_ref[:, pl.ds(s0, _SUB)] += jnp.sum(jnp.where(hit, rows, 0), axis=1)
+                return carry
+
+            jax.lax.fori_loop(t_lo, jnp.minimum(t_hi, n_tiles - 1) + 1, tile, 0)
+
+        return base + cnt
+
+    base = jax.lax.fori_loop(0, block_n // _SUB, sub_chunk, carry_ref[:, 0:1])
     lane_idx = jax.lax.broadcasted_iota(jnp.int32, carry_ref.shape, 1)
-    carry_ref[...] = carry_ref[...] + jnp.where(
-        lane_idx == 0, jnp.sum(m, axis=1, keepdims=True), 0
-    )
+    carry_ref[...] = jnp.where(lane_idx == 0, base, 0)
 
 
 @functools.partial(
@@ -528,39 +550,37 @@ def select_compact_batched(
     col_parts: tuple[int, ...] | None = None,
     out_width: int = 128,
     block_n: int = 2048,
-    max_q: int = 1024,
+    max_q: int | None = None,
     interpret: bool | None = None,
-) -> jax.Array:
+) -> np.ndarray:
     """int32[Q, out_width]: per query, its matched row indices compacted
     to the front (slots past the match count stay 0 — callers slice with
     the counts from the fused pass). ``out_width`` must cover the
-    largest match count in the batch; lanes prefer multiples of 128."""
+    largest match count in the batch; it and ``block_n`` are multiples
+    of 128. Launches carry at most :func:`query_chunk` queries (lowered
+    by ``max_q``)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
+    if out_width % _SUB or block_n % _SUB:
+        raise ValueError(
+            f"out_width {out_width} and block_n {block_n} must be multiples of {_SUB}"
+        )
     keys = jnp.asarray(keys, jnp.int32)
-    res_lo = jnp.asarray(res_lo, jnp.int32)
-    res_hi = jnp.asarray(res_hi, jnp.int32)
-    limits = jnp.asarray(limits, jnp.int32)
-    Q, K_ex = res_lo.shape
+    res_lo = np.asarray(res_lo, np.int32)
+    K_ex = res_lo.shape[1]
     if col_parts is None:
         col_parts = (1,) * K_ex
     col_parts = tuple(int(p) for p in col_parts)
     if sum(col_parts) != K_ex or not all(p in (1, 2) for p in col_parts):
         raise ValueError(f"col_parts {col_parts} does not tile {K_ex} bound lanes")
-    call = functools.partial(
-        _select_call,
-        keys,
-        col_parts=col_parts,
-        out_width=out_width,
-        block_n=block_n,
-        interpret=interpret,
+    (out,) = launch_query_chunks(
+        lambda *ops: (
+            _select_call(
+                keys, *ops, col_parts=col_parts, out_width=out_width,
+                block_n=block_n, interpret=interpret,
+            ),
+        ),
+        (res_lo, res_hi, limits),
+        max_q=query_chunk(block_n, max_q),
     )
-    if Q <= max_q:
-        return call(res_lo, res_hi, limits)
-    return jnp.concatenate(
-        [
-            call(res_lo[s : s + max_q], res_hi[s : s + max_q], limits[s : s + max_q])
-            for s in range(0, Q, max_q)
-        ],
-        axis=0,
-    )
+    return out

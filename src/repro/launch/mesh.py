@@ -32,8 +32,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             " BEFORE importing jax (see launch/dryrun.py)"
         )
-    kwargs = {}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:  # older jax: no axis_types kwarg either
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devices[:n], **kwargs)
+    return jax.make_mesh(
+        shape, axes, devices=devices[:n],
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )

@@ -10,22 +10,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
-
-# These tests exercise newer-jax auto-sharding; both jax.set_mesh and
-# jax.sharding.AxisType are required (set_mesh became public API in jax
-# 0.6.2, AxisType landed with the 0.6.x explicit-sharding work) and
-# BOTH are absent from the baked-in jax 0.4.37 — skip with a reason
-# naming the minimum version instead of failing on an AttributeError in
-# the subprocess.
-pytestmark = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")),
-    reason=(
-        "needs jax.set_mesh and jax.sharding.AxisType (jax>=0.6.2; "
-        f"installed jax {jax.__version__})"
-    ),
-)
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

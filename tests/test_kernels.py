@@ -586,8 +586,13 @@ class TestEcdfHist:
         assert got.sum() == 5000
 
     def test_large_bins_fallback_to_ref(self, rng):
+        """Past the kernel's 4096-bin budget the entry raises instead of
+        silently answering from the reference; the reference itself
+        still bins any width when asked for explicitly."""
         col = rng.integers(0, 10_000, 2000).astype(np.int32)
-        got = np.asarray(ecdf_hist(col, n_bins=5000, bin_width=2))
+        with pytest.raises(ValueError, match="4096"):
+            ecdf_hist(col, n_bins=5000, bin_width=2)
+        got = np.asarray(ecdf_hist(col, n_bins=5000, bin_width=2, use_pallas=False))
         want = np.asarray(ecdf_hist_ref(jnp.asarray(col), n_bins=5000, bin_width=2))
         np.testing.assert_allclose(got, want)
 

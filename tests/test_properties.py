@@ -182,11 +182,16 @@ def test_property_device_table_matches_numpy_engine(seed, n):
     """End-to-end property: a device-resident table answers mixed
     sum/count batches (including empty ranges) identically to the numpy
     engine — counts exact, sums to float32 tolerance."""
+    from repro.core import KeySchema
+
     rng = np.random.default_rng(seed)
     kc = {"x": rng.integers(0, 12, n), "y": rng.integers(0, 12, n)}
     vc = {"m": rng.uniform(0, 1, n), "w": rng.uniform(-3, 3, n)}
-    dev = SortedTable.from_columns(kc, vc, ("x", "y")).place_on_device()
-    host = SortedTable.from_columns(kc, vc, ("x", "y"))
+    # the key domain is fixed, not inferred from a small draw: query
+    # bounds range over all of [0, 16) whatever values the rows hold
+    schema = KeySchema({"x": 4, "y": 4})
+    dev = SortedTable.from_columns(kc, vc, ("x", "y"), schema).place_on_device()
+    host = SortedTable.from_columns(kc, vc, ("x", "y"), schema)
     qs = []
     for _ in range(8):
         f = {}
@@ -375,12 +380,16 @@ def test_property_merge_kernel_matches_lexsort_oracle(seed, n, n_runs):
     """Property: the k-way merge-path kernel's permutation equals the
     lexsort oracle AND the incrementally maintained row_map for any run
     stack, and compaction preserves every query result."""
+    from repro.core import KeySchema
     from repro.kernels import merge_run_positions, merge_run_positions_ref
 
     rng = np.random.default_rng(seed)
     kc = {"x": rng.integers(0, 6, n), "y": rng.integers(0, 6, n)}
     vc = {"m": rng.uniform(0, 1, n)}
-    t = SortedTable.from_columns(kc, vc, ("x", "y")).place_on_device()
+    # a fixed key domain: later runs and the query draw from all of
+    # [0, 6) whatever a small first run happens to hold
+    schema = KeySchema({"x": 3, "y": 3})
+    t = SortedTable.from_columns(kc, vc, ("x", "y"), schema).place_on_device()
     for _ in range(n_runs - 1):
         m = int(rng.integers(1, 80))
         t = t.merge_insert(
