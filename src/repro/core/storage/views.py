@@ -212,8 +212,9 @@ def serve_view_many(table, queries, *, trace=None, view_stats=None) -> list:
     partials sequentially in float32, ascending block order — bits
     equal to the fused full-scan launch (see ``kernels.block_agg``).
 
-    ``trace`` records one ``view.serve`` span; ``view_stats`` (a dict,
-    or None) accumulates ``hits`` (queries answered) and
+    ``trace`` records one ``view.serve`` span, with a ``view.boundary``
+    child around the boundary rescan when there is one; ``view_stats``
+    (a dict, or None) accumulates ``hits`` (queries answered) and
     ``boundary_rows`` (rows streamed through boundary rescans — the
     honest residual scan cost a view hit still pays)."""
     from repro.core.table import ScanResult, slab_bounds_many
@@ -279,12 +280,19 @@ def serve_view_many(table, queries, *, trace=None, view_stats=None) -> list:
         for p, i in enumerate(pair_q):
             p_lo[p] = wlo[i]
             p_hi[p] = np.maximum(whi[i], wlo[i])  # empty slots: lo == hi
+        bs = (
+            sp.child("view.boundary", pairs=len(pair_sel))
+            if sp is not None
+            else None
+        )
         bvals = np.asarray(
             boundary_block_sums(
                 state["values_tile"], pair_sel, pair_block, p_lo, p_hi,
                 block_n=bn,
             )
         )
+        if bs is not None:
+            bs.end()
 
     out: list[ScanResult] = []
     for i, q in enumerate(queries):

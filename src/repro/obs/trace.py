@@ -58,6 +58,10 @@ Engine read path (tracer-clock timestamps):
                                    scan); attrs ``queries``,
                                    ``boundary_rows``,
                                    ``boundary_blocks``
+.. ``view.boundary``               child of ``view.serve``, opened only
+                                   when some block is partly covered:
+                                   the boundary-rescan launch and its
+                                   host sync; attr ``pairs``
 .. ``engine.host_scan``            NumPy fallback when the column family
                                    is not device-resident
 .. ``kernel.scan_launch``          fused device locate+scan launch wall
@@ -101,6 +105,20 @@ for ``frontdoor.*`` spans (passed explicitly via ``t=``), or
 :class:`TickClock` — a deterministic integer counter — when byte-exact
 trace equality across runs matters (chaos replay). Span ids are
 sequential per tracer, so identity is also deterministic.
+
+Profiler clock
+==============
+
+A tracer on the wall clock (the default) also writes every span it
+times itself into the JAX profiler's host trace, as a ``TraceMe`` of
+the same name from the span's opening to :meth:`Span.end`, while a
+profiler trace is being recorded. The engine read path (``engine.*``,
+``view.*``, ``kernel.*``) therefore sits on the clock of the device's
+operations, and a device idle gap can be named by the spans covering
+it. Spans opened with an explicit ``t=`` (the front door's virtual
+clock) and every span of a tracer on another clock (:class:`TickClock`)
+write no event. The events need not nest, and may end on another
+thread.
 """
 
 from __future__ import annotations
@@ -109,6 +127,15 @@ import time
 from typing import Any, Callable, Iterator
 
 __all__ = ["Span", "TickClock", "Tracer", "walk"]
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class TickClock:
@@ -136,11 +163,11 @@ class Span:
     consistent by construction."""
 
     __slots__ = ("tracer", "name", "span_id", "parent_id", "t_start",
-                 "t_end", "attrs", "children")
+                 "t_end", "attrs", "children", "_event")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent_id: int | None, t_start: float,
-                 attrs: dict[str, Any]):
+                 attrs: dict[str, Any], event: Any = None):
         self.tracer = tracer
         self.name = name
         self.span_id = span_id
@@ -149,6 +176,7 @@ class Span:
         self.t_end: float | None = None
         self.attrs = attrs
         self.children: list[Span] = []
+        self._event = event  # open profiler TraceMe, if any
 
     def child(self, name: str, *, t: float | None = None, **attrs: Any) -> "Span":
         """Open a child span (explicit parent: ``self``)."""
@@ -159,6 +187,9 @@ class Span:
     def end(self, *, t: float | None = None, **attrs: Any) -> "Span":
         """Close the span; extra attrs merge in. Returns self."""
         self.t_end = self.tracer.now() if t is None else float(t)
+        if self._event is not None:
+            self._event.__exit__(None, None, None)
+            self._event = None
         if attrs:
             self.attrs.update(attrs)
         return self
@@ -214,7 +245,9 @@ class Tracer:
     ``clock`` is any zero-arg callable returning a float; the default
     is ``time.perf_counter``.  ``Tracer(clock=TickClock())`` gives
     fully deterministic traces.  The tracer keeps a list of root spans
-    (``roots``) so a harness can export everything it produced.
+    (``roots``) so a harness can export everything it produced.  On the
+    wall clock it also writes its own spans into the profiler's trace
+    (module docstring, "Profiler clock").
     """
 
     def __init__(self, clock: Callable[[], float] | None = None):
@@ -222,6 +255,9 @@ class Tracer:
         self.roots: list[Span] = []
         self._next_id = 0
         self.spans_started = 0
+        self._annotation = (
+            _profiler_annotation() if self.clock is time.perf_counter else None
+        )
 
     def now(self) -> float:
         return self.clock()
@@ -231,8 +267,14 @@ class Tracer:
         sid = self._next_id
         self._next_id = sid + 1
         self.spans_started += 1
+        ann = self._annotation
+        if t is None and ann is not None and ann.is_enabled():
+            event = ann(name)
+            event.__enter__()
+        else:
+            event = None
         t0 = self.now() if t is None else float(t)
-        return Span(self, name, sid, parent_id, t0, attrs)
+        return Span(self, name, sid, parent_id, t0, attrs, event)
 
     def root(self, name: str, *, t: float | None = None, **attrs: Any) -> Span:
         """Open a new root span (one per request / probe / batch)."""

@@ -53,13 +53,11 @@ Observability
 
 The decision counters live on a
 :class:`~repro.obs.metrics.MetricsRegistry` (``stats`` is a
-read-through dict view; ``reset_stats()`` is one registry reset), and
-the registry additionally carries virtual-time latency histograms
-(``queue_wait_seconds``, ``request_latency_seconds``,
-``batch_service_seconds``). :data:`REFUSAL_COUNTERS` and
-:data:`RUNG_COUNTERS` are the audit inventories: every typed refusal
-kind and every ladder rung maps onto a registry counter name, and a
-test walks those inventories against the catalog.
+read-through dict view; ``reset_stats()`` is one registry reset).
+:data:`REFUSAL_COUNTERS` and :data:`RUNG_COUNTERS` are the audit
+inventories: every typed refusal kind and every ladder rung maps onto
+a registry counter name, and a test walks those inventories against
+the catalog.
 
 Pass ``tracer=`` (a :class:`~repro.obs.trace.Tracer`) to record one
 ``frontdoor.request`` span tree per request: admission → queue →
@@ -252,8 +250,8 @@ class FrontDoor:
         The ladder thresholds, in units of ``max_wait`` (rungs 1–3
         above).
     metrics:
-        Registry for the decision counters and latency histograms; a
-        private one is created when omitted.
+        Registry for the decision counters; a private one is created
+        when omitted.
     tracer, slow_log, slow_log_k:
         Optional request tracing: with ``tracer`` set, every request
         grows a ``frontdoor.request`` span tree and completed trees
@@ -313,9 +311,6 @@ class FrontDoor:
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._ctr = {n: self.metrics.counter(n) for n in FRONTDOOR_COUNTERS}
         self._depth_gauge = self.metrics.gauge("max_queue_depth")
-        self._h_queue_wait = self.metrics.histogram("queue_wait_seconds")
-        self._h_latency = self.metrics.histogram("request_latency_seconds")
-        self._h_service = self.metrics.histogram("batch_service_seconds")
         self.tracer = tracer
         if slow_log is not None:
             self.slow_log = slow_log
@@ -333,8 +328,8 @@ class FrontDoor:
         return d
 
     def reset_stats(self) -> None:
-        """Zero every counter, gauge, and histogram in one registry
-        reset (handles stay live)."""
+        """Zero every counter and gauge in one registry reset (handles
+        stay live)."""
         self.metrics.reset()
 
     # -- tracing helpers ---------------------------------------------------
@@ -670,7 +665,6 @@ class FrontDoor:
                 self._release(m)
                 self._ctr[REFUSAL_COUNTERS["deadline"]].inc()
                 latency = done - m.req.arrival_s
-                self._h_latency.observe(latency)
                 self._finish(m, done, latency, status=DEADLINE)
                 responses[m.idx] = Response(
                     status=DEADLINE,
@@ -682,7 +676,6 @@ class FrontDoor:
         wall = time.perf_counter() - t0
         reported = sum(rep.wall_seconds for _sr, rep in out)
         service = max(wall, reported)
-        self._h_service.observe(service)
         done = launch + service
         if batch_span is not None:
             batch_span.end(t=done)
@@ -692,9 +685,7 @@ class FrontDoor:
             self._release(m)
             q_wait = launch - m.req.arrival_s
             self.queue_wait.record(q_wait)
-            self._h_queue_wait.observe(q_wait)
             latency = done - m.req.arrival_s
-            self._h_latency.observe(latency)
             d = m.req.deadline_s
             if d is not None and latency > d:
                 # the answer exists but landed late — refuse it openly

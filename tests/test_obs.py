@@ -494,3 +494,98 @@ class TestViewObservability:
         root.end()
         assert root.find("view.serve") is None
         assert eng.stats["view_hits"] == 0
+
+    @pytest.mark.parametrize(
+        "query, boundary",
+        [
+            # one slab inside the single row block: a partly covered
+            # block, rescanned at its boundary
+            (Query({"k0": Eq(3)}, agg="sum", value_col="metric"), True),
+            # every row: the block is wholly covered, its partial serves
+            (Query({}, agg="sum", value_col="metric"), False),
+        ],
+    )
+    def test_view_boundary_span_only_when_blocks_are_rescanned(
+        self, query, boundary
+    ):
+        eng, _ = _engine(512, device_resident=True, views=True)
+        tracer = Tracer()
+        root = tracer.root("test.root")
+        eng.read_many(_CF, [query], trace=root)
+        root.end()
+        _assert_tree_integrity(tracer)
+        sv = root.find("view.serve")
+        assert (sv.attrs["boundary_blocks"] > 0) is boundary
+        bs = sv.find_all("view.boundary")
+        assert len(bs) == int(boundary)
+        assert root.find_all("view.boundary") == bs
+        for b in bs:
+            assert b.parent_id == sv.span_id
+            assert b.attrs["pairs"] == sv.attrs["boundary_blocks"]
+            assert b.t_end is not None and 0 < b.wall <= sv.wall
+
+
+# -- the profiler's clock ----------------------------------------------------
+
+
+def _host_events(trace_dir) -> dict[str, list[float]]:
+    """``{name: [seconds, ...]}`` of the ``test.*`` events on the host
+    planes of the profiler trace written under ``trace_dir``."""
+    import jax
+
+    (path,) = trace_dir.glob("**/*.xplane.pb")
+    out: dict[str, list[float]] = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("test."):
+                    out.setdefault(ev.name, []).append(ev.duration_ns * 1e-9)
+    return out
+
+
+class TestProfilerClock:
+    def test_wall_clock_spans_reach_the_profiler_host_trace(self, tmp_path):
+        import threading
+        import time
+
+        import jax
+
+        wall, ticks = Tracer(), Tracer(clock=TickClock())
+        nap = 5e-3
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            root = wall.root("test.root")
+            child = root.child("test.child")
+            time.sleep(nap)
+            child.end()
+            # closed out of order: the outer span ends first
+            outer = root.child("test.outer")
+            inner = outer.child("test.inner")
+            time.sleep(nap)
+            outer.end()
+            time.sleep(nap)
+            inner.end()
+            # ended on another thread, as a flush-pool span is
+            pooled = root.child("test.pooled")
+            time.sleep(nap)
+            t = threading.Thread(target=pooled.end)
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
+            # virtual-clock and tick-clock spans write no event
+            root.child("test.virtual", t=1.0).end(t=2.0)
+            ticks.root("test.tick").child("test.tick_child").end()
+            root.end()
+        finally:
+            jax.profiler.stop_trace()
+        events = _host_events(tmp_path)
+        spans = {s.name: s for s in walk(root)}
+        assert set(events) == set(spans) - {"test.virtual"}
+        for name, durations in events.items():
+            (secs,) = durations
+            w = spans[name].wall
+            # the event brackets the span's own clock reads
+            assert w - 1e-6 <= secs <= 1.5 * w + 1e-3, (name, secs, w)
+        assert events["test.inner"][0] > events["test.outer"][0] + nap / 2
