@@ -203,6 +203,7 @@ __all__ = [
     "FAULT_COUNTERS",
     "REPAIR_COUNTERS",
     "VIEW_COUNTERS",
+    "SELECT_COUNTERS",
 ]
 
 #: Tunable read consistency levels (Cassandra's CL, read side): how
@@ -289,6 +290,8 @@ ENGINE_COUNTERS = (
     "view_hits",
     "view_boundary_rows",
     "view_rebuilds",
+    "select_sparse_launches",
+    "select_full_launches",
 )
 
 #: Typed refusal/fault → the registry counter that records it. Every
@@ -321,6 +324,16 @@ VIEW_COUNTERS = (
     "view_hits",
     "view_boundary_rows",
     "view_rebuilds",
+)
+
+#: Device select compaction launches, by grid: ``sparse`` read
+#: only the row blocks holding each query's slab window (one sorted
+#: run, narrow slabs), ``full`` streamed every row block (appended
+#: write runs, or slabs covering much of the table). Audited like
+#: ``VIEW_COUNTERS``.
+SELECT_COUNTERS = (
+    "select_sparse_launches",
+    "select_full_launches",
 )
 
 
@@ -675,6 +688,9 @@ class HREngine:
         self._view_hits = self.metrics.counter("view_hits")
         self._view_boundary_rows = self.metrics.counter("view_boundary_rows")
         self._view_rebuilds = self.metrics.counter("view_rebuilds")
+        # device select compactions: block-sparse and full-stream launches
+        self._select_sparse = self.metrics.counter("select_sparse_launches")
+        self._select_full = self.metrics.counter("select_full_launches")
         self._pool: ThreadPoolExecutor | None = None
 
     @property
@@ -767,6 +783,9 @@ class HREngine:
             "view_hits": int(self._view_hits.value),
             "view_boundary_rows": int(self._view_boundary_rows.value),
             "view_rebuilds": int(self._view_rebuilds.value),
+            # device select compaction launches, by grid
+            "select_sparse_launches": int(self._select_sparse.value),
+            "select_full_launches": int(self._select_full.value),
         }
 
     def reset_stats(self) -> None:
@@ -1565,10 +1584,12 @@ class HREngine:
             else None
         )
         vstats = {"hits": 0, "boundary_rows": 0} if cf.views else None
+        sstats = {"sparse_launches": 0, "full_launches": 0}
         t0 = self._scan_timer()
         miss_scans = (
             table.execute_many(
-                [group[j] for j in miss_j], trace=sc, view_stats=vstats
+                [group[j] for j in miss_j], trace=sc, view_stats=vstats,
+                select_stats=sstats,
             )
             if miss_j
             else []
@@ -1579,6 +1600,10 @@ class HREngine:
         if vstats is not None and vstats["hits"]:
             self._view_hits.inc(vstats["hits"])
             self._view_boundary_rows.inc(vstats["boundary_rows"])
+        if sstats["sparse_launches"]:
+            self._select_sparse.inc(sstats["sparse_launches"])
+        if sstats["full_launches"]:
+            self._select_full.inc(sstats["full_launches"])
         if miss_j and self.failure_detector is not None:
             # one latency sample per executed group — cache hits are
             # not operations the node performed

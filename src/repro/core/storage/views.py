@@ -188,18 +188,20 @@ def verify_views(table, *, use_pallas: bool = True) -> bool:
 
 def _run_windows(vs, bounds) -> tuple[np.ndarray, np.ndarray]:
     """Global row-index windows int64[Q, R] (lo inclusive, hi
-    exclusive) of each query's slab in each resident run: two
-    vectorized searchsorteds per run over its sorted packed keys
-    (``bounds`` comes from ``slab_bounds_many`` — hi inclusive, so
-    ``side="right"`` matches the fused kernel's ``<=`` rank)."""
+    exclusive) of each query's slab in each resident run
+    (``slab_windows`` over the run's sorted packed keys; ``bounds``
+    comes from ``slab_bounds_many``)."""
+    from repro.core.table import slab_windows
+
     n_q = bounds.shape[0]
     runs = vs["run_packed"]
     wlo = np.empty((n_q, len(runs)), np.int64)
     whi = np.empty((n_q, len(runs)), np.int64)
     start = 0
     for r, p in enumerate(runs):
-        wlo[:, r] = start + np.searchsorted(p, bounds[:, 0], side="left")
-        whi[:, r] = start + np.searchsorted(p, bounds[:, 1], side="right")
+        lo, hi = slab_windows(p, bounds)
+        wlo[:, r] = start + lo
+        whi[:, r] = start + hi
         start += int(p.shape[0])
     return wlo, whi
 

@@ -182,14 +182,33 @@ def slab_bounds_many(
     exact equivalent). Queries with a degenerate (empty) filter range
     get ``lo = 0, hi = −1``.
     """
-    los, his, nonempty = _slab_col_bounds(queries, layout, schema)
-    n_q = len(queries)
+    return pack_slab_bounds(*_slab_col_bounds(queries, layout, schema), layout, schema)
+
+
+def pack_slab_bounds(
+    los: np.ndarray, his: np.ndarray, nonempty: np.ndarray,
+    layout: Sequence[str], schema: KeySchema,
+) -> np.ndarray:
+    """Pack :func:`_slab_col_bounds`'s per-column bounds into the
+    int64[Q, 2] composite-key bounds of :func:`slab_bounds_many`."""
     # MSB-first packing, same field shifts as keys.pack_tuple
     sh = np.asarray(_field_shifts(schema, layout), dtype=np.int64)
-    out = np.empty((n_q, 2), dtype=np.int64)
+    out = np.empty((los.shape[0], 2), dtype=np.int64)
     out[:, 0] = ((los << sh).sum(axis=1)) * nonempty
     out[:, 1] = np.where(nonempty, (his << sh).sum(axis=1), -1)
     return out
+
+
+def slab_windows(packed: np.ndarray, bounds: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Row windows ``(lo, hi)``, each int[Q], ``[lo, hi)``, of packed-key
+    slab bounds (:func:`slab_bounds_many`) in one sorted run: two
+    vectorized searchsorteds. The inclusive upper key with
+    ``side="right"`` equals the scalar ``(hi + 1, side="left")`` without
+    the int64 wrap at 63-bit schemas, and matches the fused kernel's
+    ``<=`` rank."""
+    lo = np.searchsorted(packed, bounds[:, 0], side="left")
+    hi = np.searchsorted(packed, bounds[:, 1], side="right")
+    return lo, hi
 
 
 @dataclasses.dataclass
@@ -507,11 +526,9 @@ class SortedTable:
             from repro.kernels import table_slab_locate_many
 
             return table_slab_locate_many(self, queries)
-        bounds = slab_bounds_many(queries, self.layout, self.schema)
-        lo = np.searchsorted(self.packed, bounds[:, 0], side="left")
-        # inclusive upper key + side="right" ≡ scalar (hi + 1, side="left")
-        # without the int64 wrap at 63-bit schemas
-        hi = np.searchsorted(self.packed, bounds[:, 1], side="right")
+        lo, hi = slab_windows(
+            self.packed, slab_bounds_many(queries, self.layout, self.schema)
+        )
         return np.stack([lo, hi], axis=1).astype(np.int64)
 
     def execute(self, query: Query) -> ScanResult:
@@ -535,7 +552,8 @@ class SortedTable:
         return self._scan_slab(query, lo, hi)
 
     def execute_many(
-        self, queries: Sequence[Query], *, trace=None, view_stats=None
+        self, queries: Sequence[Query], *, trace=None, view_stats=None,
+        select_stats=None,
     ) -> list[ScanResult]:
         """Batched ``execute``.
 
@@ -563,7 +581,9 @@ class SortedTable:
         per-block partials — O(blocks touched) instead of the O(N)
         fused stream, bit-identical by construction — and
         ``view_stats`` (a dict, or None) receives their ``hits`` /
-        ``boundary_rows`` tallies for the engine's counters.
+        ``boundary_rows`` tallies for the engine's counters, as
+        ``select_stats`` receives the device select compaction's
+        ``sparse_launches`` / ``full_launches``.
         """
         queries = list(queries)
         if not queries:
@@ -588,7 +608,8 @@ class SortedTable:
             from repro.kernels import table_execute_device_many
 
             out = table_execute_device_many(
-                self, [queries[i] for i in dev_idx], trace=trace
+                self, [queries[i] for i in dev_idx], trace=trace,
+                select_stats=select_stats,
             )
             for i, r in zip(dev_idx, out):
                 results[i] = r

@@ -34,14 +34,19 @@ from .ecdf_hist import ecdf_hist_pallas
 from .merge_runs import merge_rank_batched, merge_run_positions
 from .scan_agg import (
     WIDE_LANE_BITS,
+    query_chunk,
     scan_agg_batched_pallas,
     scan_agg_batched_qgrid_pallas,
     scan_agg_pallas,
 )
 from .slab_locate import (
+    SELECT_SPARSE_BLOCK_N,
     residual_membership_batched,
     scan_agg_locate_batched,
     select_compact_batched,
+    select_compact_sparse_batched,
+    select_sparse_fits,
+    select_sparse_steps,
     slab_locate_batched,
 )
 
@@ -568,12 +573,13 @@ def table_scan_device_many(
 def _device_query_bounds(table, queries, col_parts, n_rows):
     """Host-side O(Q·K) operand prep for the device read kernels: the
     residual per-lane bounds (exclusive hi), the slab key lane bounds
-    (inclusive hi, from the same walk ``slab_bounds_many`` packs), and
-    the per-query [start, stop) row windows. Empty queries are encoded
-    as an impossible slab key (hi lanes = −1) and a (0, 0) window.
-    Raises exactly where the host walk raises (out-of-domain bounds on a
-    nonempty query); performs zero searchsorted calls."""
-    from repro.core.table import _slab_col_bounds
+    (inclusive hi, from the same walk ``slab_bounds_many`` packs), the
+    per-query [start, stop) row windows, and the packed slab bounds
+    (``slab_bounds_many``'s). Empty queries are encoded as an impossible
+    slab key (hi lanes = −1) and a (0, 0) window. Raises exactly where
+    the host walk raises (out-of-domain bounds on a nonempty query);
+    performs zero searchsorted calls."""
+    from repro.core.table import _slab_col_bounds, pack_slab_bounds
 
     names = list(table.layout)
     # the slab walk first: it owns bound validation, so the device path
@@ -589,7 +595,8 @@ def _device_query_bounds(table, queries, col_parts, n_rows):
     res_lo, res_hi = _expand_bounds(bounds, col_parts)
     limits = np.zeros((len(queries), 2), np.int64)
     limits[:, 1] = np.where(nonempty, n_rows, 0)
-    return res_lo, res_hi, slab_lo, slab_hi, limits
+    slab_keys = pack_slab_bounds(los, his, nonempty, names, table.schema)
+    return res_lo, res_hi, slab_lo, slab_hi, limits, slab_keys
 
 
 def table_slab_locate_many(
@@ -610,7 +617,7 @@ def table_slab_locate_many(
             "single sorted run — use place_on_device(rebuild=True)"
         )
     col_parts = state["col_parts"]
-    _, _, slab_lo, slab_hi, limits = _device_query_bounds(
+    _, _, slab_lo, slab_hi, limits, _ = _device_query_bounds(
         table, queries, col_parts, state["n_rows"]
     )
     fn = slab_locate_batched if use_pallas else ref.slab_locate_batched_ref
@@ -624,7 +631,7 @@ def table_slab_locate_many(
 
 def table_execute_device_many(
     table, queries, *, block_n: int = DEVICE_BLOCK_N, use_pallas: bool = True,
-    trace=None,
+    trace=None, select_stats=None,
 ) -> list:
     """Serve a sum/count/select batch entirely from a table's resident
     device arrays: one fused locate+scan launch computes every query's
@@ -641,11 +648,21 @@ def table_execute_device_many(
     a resident table) ``row_map`` translates select indices back to
     host row order.
 
+    On a single sorted run the compaction reads only the row blocks
+    that hold each select's slab window (host ``slab_windows`` over
+    ``table.packed``; :func:`select_compact_sparse_batched`), while that
+    block-sparse grid reads at most half the rows of the full stream
+    (:func:`select_sparse_fits`); otherwise it streams every row block
+    (:func:`select_compact_batched`). ``select_stats`` (a dict, or None)
+    counts the launches of each kind, as ``sparse_launches`` and
+    ``full_launches``.
+
     ``trace`` (an open ``repro.obs.Span``, or None) wraps each device
     launch *wall* — launch plus the ``np.asarray`` result fetch, i.e.
     including the host sync — as ``kernel.scan_launch`` /
-    ``kernel.select_compact`` child spans."""
-    from repro.core.table import ScanResult
+    ``kernel.select_compact`` child spans; the latter carries
+    ``blocks``, the 128-row blocks its launches read."""
+    from repro.core.table import ScanResult, slab_windows
 
     queries = list(queries)
     if not queries:
@@ -665,7 +682,7 @@ def table_execute_device_many(
             if q.value_col not in value_rows:
                 raise KeyError(q.value_col)
     col_parts = state["col_parts"]
-    res_lo, res_hi, slab_lo, slab_hi, limits = _device_query_bounds(
+    res_lo, res_hi, slab_lo, slab_hi, limits, slab_keys = _device_query_bounds(
         table, queries, col_parts, state["n_rows"]
     )
     sel = np.array(
@@ -731,6 +748,7 @@ def table_execute_device_many(
         if trace is not None and sel_idx
         else None
     )
+    blocks = 0  # 128-row blocks the compaction launches read
     if sel_idx:
         mmax = int(matched[sel_idx].max())
         if mmax == 0:
@@ -740,29 +758,56 @@ def table_execute_device_many(
             width = 128
             while width < mmax:  # pow-2 lanes bucket the jit cache
                 width *= 2
-            # bound the whole output block, not just its width: chunk the
-            # batch so Q_pad * width stays inside the element budget
-            q_chunk = max(8, (SELECT_COMPACT_MAX_ELEMS // width) // 8 * 8)
+            # one launch per chunk: Q_pad * width stays inside the output
+            # block's element budget, and Q inside the kernels' query cap
+            q_chunk = min(
+                max(8, (SELECT_COMPACT_MAX_ELEMS // width) // 8 * 8),
+                query_chunk(block_n),
+            )
+            n_pad = int(state["keys"].shape[1])
+            windows = None
+            if state.get("n_runs", 1) == 1:
+                # one sorted run: device rows are in ``table.packed``
+                # order, so every residual match lies in its query's
+                # slab window and the rows outside every window can go
+                windows = np.stack(
+                    slab_windows(table.packed, slab_keys[sel_idx]), axis=1
+                )
             for s in range(0, len(sel_idx), q_chunk):
                 chunk = sel_idx[s : s + q_chunk]
-                if use_pallas:
-                    idx = select_compact_batched(
-                        state["keys"], res_lo[chunk], res_hi[chunk],
-                        limits[chunk], col_parts=col_parts, out_width=width,
-                        block_n=block_n,
-                    )
+                lim = limits[chunk]
+                sparse = windows is not None and select_sparse_fits(
+                    windows[s : s + q_chunk], n_pad, max_q=q_chunk
+                )
+                if sparse:
+                    lim = windows[s : s + q_chunk]
+                    blocks += int(select_sparse_steps(lim)[1].sum())
                 else:
+                    blocks += n_pad // SELECT_SPARSE_BLOCK_N
+                if select_stats is not None:
+                    key = "sparse_launches" if sparse else "full_launches"
+                    select_stats[key] = select_stats.get(key, 0) + 1
+                if not use_pallas:
                     idx = ref.select_compact_batched_ref(
                         state["keys"], jnp.asarray(res_lo[chunk]),
-                        jnp.asarray(res_hi[chunk]),
-                        jnp.asarray(limits[chunk], jnp.int32),
+                        jnp.asarray(res_hi[chunk]), jnp.asarray(lim, jnp.int32),
                         col_parts=col_parts, out_width=width,
+                    )
+                elif sparse:
+                    idx = select_compact_sparse_batched(
+                        state["keys"], res_lo[chunk], res_hi[chunk], lim,
+                        col_parts=col_parts, out_width=width, max_q=q_chunk,
+                    )
+                else:
+                    idx = select_compact_batched(
+                        state["keys"], res_lo[chunk], res_hi[chunk], lim,
+                        col_parts=col_parts, out_width=width, block_n=block_n,
                     )
                 idx = np.asarray(idx)
                 for j, i in enumerate(chunk):
                     selected[i] = _host_rows(idx[j, : int(matched[i])])
     if kc is not None:
-        kc.end()
+        kc.end(blocks=blocks)
 
     out = []
     for i, q in enumerate(queries):

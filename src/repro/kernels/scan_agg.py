@@ -169,6 +169,12 @@ def query_chunk(block_n: int, max_q: int | None = None) -> int:
     return cap
 
 
+def query_bucket(n: int, max_q: int) -> int:
+    """Queries a launch of ``n`` is padded to: a power of two, at least
+    8, at most ``max_q``."""
+    return min(max_q, max(8, 1 << max(n - 1, 0).bit_length()))
+
+
 def launch_query_chunks(call, operands, *, max_q: int) -> tuple[np.ndarray, ...]:
     """Run ``call`` over the query axis (axis 0 of every operand) in
     launches of at most ``max_q`` queries. Each launch is zero-padded on
@@ -182,7 +188,7 @@ def launch_query_chunks(call, operands, *, max_q: int) -> tuple[np.ndarray, ...]
     parts = []
     for s in range(0, max(n_q, 1), max_q):
         n = min(max_q, n_q - s)
-        bucket = min(max_q, max(8, 1 << max(n - 1, 0).bit_length()))
+        bucket = query_bucket(n, max_q)
         chunk = [
             np.pad(x[s : s + n], [(0, bucket - n)] + [(0, 0)] * (x.ndim - 1))
             for x in operands

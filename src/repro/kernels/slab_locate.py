@@ -47,6 +47,14 @@ remove those host hops.
     local`` of a pre-sized ``(Q, out_width)`` output in one-hot form —
     no cumsum and no dynamic scatter, both of which Mosaic refuses.
 
+``select_compact_sparse_batched``
+    The same compaction over each query's slab window alone: on a
+    sorted run every residual match lies in its query's slab, so a
+    ``(Q, B)`` grid visits just the 128-row blocks of each window
+    (scalar-prefetched block indices drive the key DMA), each query
+    accumulating its own output row in ascending row order — the full
+    stream's output, bit for bit, for a few blocks instead of all.
+
 Every wrapper launches at most :func:`query_chunk` queries at a time,
 the VMEM bound of the ``(Q_pad, block_n)`` per-step intermediates.
 
@@ -63,8 +71,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .scan_agg import _lex_ge, _lex_lt, _pad_to, launch_query_chunks, query_chunk
+from .scan_agg import (
+    _lex_ge,
+    _lex_lt,
+    _pad_to,
+    launch_query_chunks,
+    query_bucket,
+    query_chunk,
+)
 
 __all__ = [
     "slab_locate_kernel",
@@ -73,6 +89,10 @@ __all__ = [
     "scan_agg_locate_batched",
     "select_compact_kernel",
     "select_compact_batched",
+    "SELECT_SPARSE_BLOCK_N",
+    "select_sparse_steps",
+    "select_sparse_fits",
+    "select_compact_sparse_batched",
     "residual_membership_batched",
 ]
 
@@ -433,24 +453,68 @@ def scan_agg_locate_batched(
 # rows per select sub-chunk, and output slots per scatter tile: one lane
 # width, so the prefix-sum matmul is (Q, 128) x (128, 128)
 _SUB = 128
+# rows per step of the sparse select grid: one sub-chunk, so a window of
+# a few rows costs one step and one 128-row fetch
+SELECT_SPARSE_BLOCK_N = _SUB
+
+
+def _compact_sub_chunk(keys, ridx, lo, hi, res_lo, res_hi, col_parts, base, out_ref, tri, slot):
+    """Compact one 128-row sub-chunk: ``keys`` its (K, 128) key lanes,
+    ``ridx`` its (1, 128) global row indices, ``[lo, hi)`` each query's
+    row window (``(Q, 1)`` columns or scalars) and ``base`` (Q, 1) each
+    query's matches before this sub-chunk. Matches land in ``out_ref``
+    at ``base + exclusive-prefix-sum``; returns ``base + count``.
+
+    The prefix sum is one MXU matmul of the 0/1 match mask against the
+    strictly upper-triangular ones matrix ``tri`` (exact: 0/1 operands,
+    sums of at most 128). The scatter is its one-hot form: for each
+    128-slot output tile the sub-chunk can reach, ``(pos == slot) &
+    matched`` selects each matched row's index into its slot, summed
+    over rows — every matched row owns its slot, so the sum is exact.
+    Sub-chunks without matches skip the scatter."""
+    n_tiles = out_ref.shape[1] // _SUB
+    valid = (ridx >= lo) & (ridx < hi)
+    matched = _residual_pred(keys, res_lo, res_hi, col_parts, valid)
+    cnt = jnp.sum(matched.astype(jnp.int32), axis=1, keepdims=True)
+
+    @pl.when(jnp.max(cnt) > 0)
+    def _scatter():
+        local = jnp.dot(
+            matched.astype(jnp.float32), tri, preferred_element_type=jnp.float32
+        ).astype(jnp.int32)
+        pos = jnp.where(matched, base + local, -1)
+        # output tiles this sub-chunk's matches reach, over all queries
+        t_lo = jnp.min(jnp.where(cnt > 0, base, 1 << 30)) // _SUB
+        t_hi = jnp.max(jnp.where(cnt > 0, base + cnt - 1, -1)) // _SUB
+        rows = ridx[:, :, None]
+
+        def tile(t, carry):
+            s0 = pl.multiple_of(t * _SUB, _SUB)
+            hit = pos[:, :, None] == s0 + slot  # (Q, rows, slots)
+            out_ref[:, pl.ds(s0, _SUB)] += jnp.sum(jnp.where(hit, rows, 0), axis=1)
+            return carry
+
+        jax.lax.fori_loop(t_lo, jnp.minimum(t_hi, n_tiles - 1) + 1, tile, 0)
+
+    return base + cnt
+
+
+def _compact_consts():
+    """The prefix-sum matrix and the output slot iota of a sub-chunk."""
+    tri = (
+        jax.lax.broadcasted_iota(jnp.int32, (_SUB, _SUB), 0)
+        < jax.lax.broadcasted_iota(jnp.int32, (_SUB, _SUB), 1)
+    ).astype(jnp.float32)
+    return tri, jax.lax.broadcasted_iota(jnp.int32, (1, 1, _SUB), 2)
 
 
 def select_compact_kernel(
     col_parts, limits_ref, keys_ref, res_lo_ref, res_hi_ref, out_ref, carry_ref
 ):
-    """One row-block step of the two-pass select: the carry accumulator
-    (lane 0) holds each query's match count over earlier blocks; this
-    block's matches land at ``carry + exclusive-prefix-sum`` of the
-    match mask.
-
-    The block is walked in 128-row sub-chunks. A sub-chunk's exclusive
-    prefix sum is one MXU matmul of its 0/1 match mask against a
-    strictly upper-triangular ones matrix (exact: 0/1 operands, sums of
-    at most 128). The scatter is its one-hot form: for each 128-slot
-    output tile the sub-chunk can reach, ``(pos == slot) & matched``
-    selects each matched row's index into its slot, summed over rows —
-    every matched row owns its slot, so the sum is exact. Sub-chunks
-    without matches skip the scatter."""
+    """One row-block step of the two-pass select, every query: the
+    carry accumulator (lane 0) holds each query's match count over
+    earlier blocks; the block is walked in 128-row sub-chunks
+    (:func:`_compact_sub_chunk`)."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -459,44 +523,18 @@ def select_compact_kernel(
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
     block_n = keys_ref.shape[1]
-    n_tiles = out_ref.shape[1] // _SUB
     limits = limits_ref[...]
     res_lo = res_lo_ref[...]
     res_hi = res_hi_ref[...]
-    tri = (
-        jax.lax.broadcasted_iota(jnp.int32, (_SUB, _SUB), 0)
-        < jax.lax.broadcasted_iota(jnp.int32, (_SUB, _SUB), 1)
-    ).astype(jnp.float32)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _SUB), 2)
+    tri, slot = _compact_consts()
 
     def sub_chunk(c, base):  # base: (Q, 1) matches before this sub-chunk
         start = pl.multiple_of(c * _SUB, _SUB)
-        keys = keys_ref[:, pl.ds(start, _SUB)]
         ridx = i * block_n + start + jax.lax.broadcasted_iota(jnp.int32, (1, _SUB), 1)
-        valid = (ridx >= limits[:, 0:1]) & (ridx < limits[:, 1:2])
-        matched = _residual_pred(keys, res_lo, res_hi, col_parts, valid)
-        cnt = jnp.sum(matched.astype(jnp.int32), axis=1, keepdims=True)
-
-        @pl.when(jnp.max(cnt) > 0)
-        def _scatter():
-            local = jnp.dot(
-                matched.astype(jnp.float32), tri, preferred_element_type=jnp.float32
-            ).astype(jnp.int32)
-            pos = jnp.where(matched, base + local, -1)
-            # output tiles this sub-chunk's matches reach, over all queries
-            t_lo = jnp.min(jnp.where(cnt > 0, base, 1 << 30)) // _SUB
-            t_hi = jnp.max(jnp.where(cnt > 0, base + cnt - 1, -1)) // _SUB
-            rows = ridx[:, :, None]
-
-            def tile(t, carry):
-                s0 = pl.multiple_of(t * _SUB, _SUB)
-                hit = pos[:, :, None] == s0 + slot  # (Q, rows, slots)
-                out_ref[:, pl.ds(s0, _SUB)] += jnp.sum(jnp.where(hit, rows, 0), axis=1)
-                return carry
-
-            jax.lax.fori_loop(t_lo, jnp.minimum(t_hi, n_tiles - 1) + 1, tile, 0)
-
-        return base + cnt
+        return _compact_sub_chunk(
+            keys_ref[:, pl.ds(start, _SUB)], ridx, limits[:, 0:1], limits[:, 1:2],
+            res_lo, res_hi, col_parts, base, out_ref, tri, slot,
+        )
 
     base = jax.lax.fori_loop(0, block_n // _SUB, sub_chunk, carry_ref[:, 0:1])
     lane_idx = jax.lax.broadcasted_iota(jnp.int32, carry_ref.shape, 1)
@@ -583,4 +621,156 @@ def select_compact_batched(
         (res_lo, res_hi, limits),
         max_q=query_chunk(block_n, max_q),
     )
+    return out
+
+
+# -- "select" over slab windows: a block-sparse grid ---------------------------
+
+
+def select_sparse_steps(limits) -> tuple[np.ndarray, np.ndarray, int]:
+    """The sparse select grid of a launch: per query, the first 128-row
+    block its ``[start, stop)`` window touches and how many blocks it
+    spans (0 for an empty window), and ``B``, the grid's steps per
+    query: the smallest power of two, at least 2, covering the widest
+    span. A window no wider than one block spans at most two, so ``B``
+    depends on window widths, not on where they fall."""
+    lim = np.asarray(limits, np.int64).reshape(-1, 2)
+    first = lim[:, 0] // _SUB
+    span = np.where(lim[:, 1] > lim[:, 0], (lim[:, 1] - 1) // _SUB - first + 1, 0)
+    steps = 2
+    while steps < span.max(initial=0):
+        steps *= 2
+    return first.astype(np.int32), span.astype(np.int32), steps
+
+
+def select_sparse_fits(limits, n_rows: int, *, max_q: int) -> bool:
+    """Whether the sparse grid reads at most half the rows of a full
+    stream: each launch of at most ``max_q`` queries, padded to its
+    power-of-two query count ``Q_pad``, takes ``Q_pad * B`` steps of 128
+    rows, against ``n_rows`` for the full stream."""
+    lim = np.asarray(limits).reshape(-1, 2)
+    for s in range(0, lim.shape[0], max_q):
+        chunk = lim[s : s + max_q]
+        steps = query_bucket(chunk.shape[0], max_q) * select_sparse_steps(chunk)[2]
+        if 2 * steps * _SUB > n_rows:
+            return False
+    return True
+
+
+def select_sparse_kernel(
+    col_parts, first_ref, span_ref, lims_ref, keys_ref, res_lo_ref, res_hi_ref,
+    out_ref, carry_ref,
+):
+    """Step ``(q, b)`` of the sparse select: query ``q``'s ``b``-th block
+    of 128 rows, fetched by the index map from the scalar-prefetched
+    ``first`` block. Steps past the query's ``span`` keep the last block
+    (no new fetch) and do nothing. Each query accumulates its own output
+    row, its blocks in ascending row order, so the output equals the
+    full stream's wherever every match lies in the window."""
+    q = pl.program_id(0)
+    b = pl.program_id(1)
+
+    @pl.when(b == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+        carry_ref[...] = jnp.zeros_like(carry_ref)
+
+    @pl.when(b < span_ref[q])
+    def _visit():
+        tri, slot = _compact_consts()
+        ridx = (first_ref[q] + b) * _SUB + jax.lax.broadcasted_iota(jnp.int32, (1, _SUB), 1)
+        base = _compact_sub_chunk(
+            keys_ref[...], ridx, lims_ref[2 * q], lims_ref[2 * q + 1],
+            res_lo_ref[...], res_hi_ref[...], col_parts, carry_ref[:, 0:1],
+            out_ref, tri, slot,
+        )
+        carry_ref[...] = jnp.broadcast_to(base, carry_ref.shape)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("col_parts", "out_width", "steps", "interpret")
+)
+def _select_sparse_call(keys, ops, *, col_parts, out_width, steps, interpret):
+    """``ops`` int32[Q, 2 * K_ex + 4] packs each query's residual bounds
+    (lo, then exclusive hi), its ``[start, stop)`` window, first block
+    and block span, so a launch makes one host-to-device copy: on a TPU
+    v5e host each small copy costs about a tenth of a millisecond,
+    several times the kernel's own device time."""
+    N = keys.shape[1]
+    Q = ops.shape[0]
+    K_ex = sum(col_parts)
+    K_pad = max(8, -(-keys.shape[0] // 8) * 8)
+    Q_pad = max(8, -(-Q // 8) * 8)
+    N_pad = -(-max(N, 1) // _SUB) * _SUB
+
+    ops = _pad_to(ops.astype(jnp.int32), Q_pad, 0, 0)
+    keys_p = _pad_to(_pad_to(keys.astype(jnp.int32), N_pad, 1, 0), K_pad, 0, 0)
+    lo_p = _pad_to(ops[:, :K_ex], K_pad, 1, 0)[:, None, :]
+    hi_p = _pad_to(ops[:, K_ex : 2 * K_ex], K_pad, 1, 0)[:, None, :]
+    lim_p = ops[:, 2 * K_ex : 2 * K_ex + 2].reshape(-1)
+    first_p = ops[:, 2 * K_ex + 2]
+    span_p = ops[:, 2 * K_ex + 3]
+
+    def key_block(q, b, first, span, lims):
+        return 0, first[q] + jnp.minimum(b, jnp.maximum(span[q] - 1, 0))
+
+    per_query = pl.BlockSpec((None, 1, K_pad), lambda q, b, *_: (q, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(select_sparse_kernel, col_parts),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(Q_pad, steps),
+            in_specs=[pl.BlockSpec((K_pad, _SUB), key_block), per_query, per_query],
+            out_specs=pl.BlockSpec((None, 1, out_width), lambda q, b, *_: (q, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((1, 128), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((Q_pad, 1, out_width), jnp.int32),
+        interpret=interpret,
+    )(first_p, span_p, lim_p, keys_p, lo_p, hi_p)
+    return out[:Q, 0]
+
+
+def select_compact_sparse_batched(
+    keys: jax.Array,  # int32[K_ex(+pad), N]
+    res_lo: jax.Array,  # int32[Q, K_ex] residual bounds, inclusive
+    res_hi: jax.Array,  # int32[Q, K_ex] residual bounds, EXCLUSIVE
+    limits: jax.Array,  # int32[Q, 2] each query's slab window
+    *,
+    col_parts: tuple[int, ...] | None = None,
+    out_width: int = 128,
+    max_q: int = 128,
+    interpret: bool | None = None,
+) -> np.ndarray:
+    """:func:`select_compact_batched` over the row blocks that hold each
+    query's ``[start, stop)`` window, not the whole table: a grid of
+    ``(Q_pad, B)`` steps (:func:`select_sparse_steps`), each one block of
+    128 rows. The output equals the full stream's over the same windows
+    bit for bit; on a sorted run, where every residual match lies in its
+    query's slab, it equals the full stream over ``[0, N)``. Launches
+    carry at most ``max_q`` queries."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    if out_width % _SUB:
+        raise ValueError(f"out_width {out_width} must be a multiple of {_SUB}")
+    res_lo = np.asarray(res_lo, np.int32)
+    K_ex = res_lo.shape[1]
+    if col_parts is None:
+        col_parts = (1,) * K_ex
+    col_parts = tuple(int(p) for p in col_parts)
+    if sum(col_parts) != K_ex or not all(p in (1, 2) for p in col_parts):
+        raise ValueError(f"col_parts {col_parts} does not tile {K_ex} bound lanes")
+    lim = np.asarray(limits, np.int32)
+
+    def launch(ops):
+        first, span, steps = select_sparse_steps(ops[:, 2 * K_ex : 2 * K_ex + 2])
+        ops = np.concatenate([ops, first[:, None], span[:, None]], axis=1)
+        return (
+            _select_sparse_call(
+                keys, ops, col_parts=col_parts, out_width=out_width, steps=steps,
+                interpret=interpret,
+            ),
+        )
+
+    ops = np.concatenate([res_lo, np.asarray(res_hi, np.int32), lim], axis=1)
+    (out,) = launch_query_chunks(launch, (ops,), max_q=max_q)
     return out

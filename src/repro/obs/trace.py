@@ -67,6 +67,8 @@ Engine read path (tracer-clock timestamps):
 .. ``kernel.scan_launch``          fused device locate+scan launch wall
                                    (includes the host sync)
 .. ``kernel.select_compact``       device select-index compaction launch
+                                   wall; attr ``blocks`` (128-row blocks
+                                   its launches read)
 .. ``engine.digest``               digest-read consistency pass; attrs
                                    ``level``, ``replicas``
 .. ``engine.read_repair``          one replica repair; attr ``replica``

@@ -124,6 +124,17 @@ def test_select_compact(shape, q, width):
     )
 
 
+@pytest.mark.parametrize("q", [64, CHUNK])
+def test_select_sparse(shape, q):
+    S = shape
+    _compiles_to_kernel(
+        slab_locate._select_sparse_call.lower(
+            S((K_PAD, N_ROWS)), S((q, 2 * 3 + 4)), col_parts=(1, 1, 1),
+            out_width=128, steps=2, interpret=False,
+        )
+    )
+
+
 def test_merge_rank(shape):
     S = shape
     w = S((CHUNK, 2))

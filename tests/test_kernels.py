@@ -570,6 +570,72 @@ class TestSelectCompact:
         )
         np.testing.assert_array_equal(got[0], np.arange(5, 133))
 
+    # slab windows of the sparse grid (128-row blocks) over 20,000 sorted
+    # rows; each case's id names what it exercises
+    SPARSE_N = 20_000
+
+    @pytest.mark.parametrize(
+        "windows,fits",
+        [
+            pytest.param([(128, 256), (0, 128), (1280, 1408)], True, id="block_edge"),
+            pytest.param([(100, 200), (250, 390), (19_990, 20_000)], True, id="straddle"),
+            pytest.param([(389, 404), (384, 512), (400, 401), (500, 512)], True, id="one_block"),
+            pytest.param([(0, 0), (300, 340), (0, 0), (7000, 7005), (0, 0)], True, id="empty_mixed"),
+            pytest.param(
+                [(a, a + w) for a, w in zip(range(50, 19_000, 1700), range(1, 23, 2))],
+                True, id="q_not_multiple_of_8",
+            ),
+            pytest.param([(0, 20_000), (10, 20)], False, id="too_wide"),
+        ],
+    )
+    def test_sparse_matches_full_and_ref(self, rng, windows, fits):
+        """The block-sparse launch over slab windows equals the full
+        stream and the oracle over the same windows, bit for bit; a
+        window too wide for half the table's rows takes the full path."""
+        from repro.kernels import select_compact_batched, select_compact_batched_ref
+        from repro.kernels.slab_locate import (
+            select_compact_sparse_batched,
+            select_sparse_fits,
+        )
+
+        n = self.SPARSE_N
+        a = np.sort(rng.integers(0, 400, n))
+        keys = np.stack([a, rng.integers(0, 10, n)]).astype(np.int32)
+        q = len(windows)
+        limits = np.asarray(windows, np.int32)
+        res_lo = np.stack([rng.integers(0, 5, q), rng.integers(0, 3, q)], 1)
+        res_hi = np.stack([np.full(q, 400), rng.integers(5, 11, q)], 1)
+        res_lo, res_hi = res_lo.astype(np.int32), res_hi.astype(np.int32)
+        counts = [
+            int(((keys[0, lo:hi] >= res_lo[j, 0]) & (keys[1, lo:hi] >= res_lo[j, 1])
+                 & (keys[1, lo:hi] < res_hi[j, 1])).sum())
+            for j, (lo, hi) in enumerate(windows)
+        ]
+        width = 128
+        while width < max(counts):
+            width *= 2
+        assert select_sparse_fits(limits, n, max_q=128) is fits
+        sparse = np.asarray(
+            select_compact_sparse_batched(keys, res_lo, res_hi, limits, out_width=width)
+        )
+        full = np.asarray(
+            select_compact_batched(
+                keys, res_lo, res_hi, limits, out_width=width, block_n=4096
+            )
+        )
+        want = np.asarray(
+            select_compact_batched_ref(
+                jnp.asarray(keys), jnp.asarray(res_lo), jnp.asarray(res_hi),
+                jnp.asarray(limits), out_width=width,
+            )
+        )
+        np.testing.assert_array_equal(sparse, full)
+        np.testing.assert_array_equal(sparse, want)
+        assert max(counts) > 0
+        for j, c in enumerate(counts):
+            assert np.all(np.diff(sparse[j, :c]) > 0)
+            assert not sparse[j, c:].any()
+
 
 class TestEcdfHist:
     @pytest.mark.parametrize("N,B,W", [(100, 8, 1), (4096, 64, 3), (10_000, 512, 2),

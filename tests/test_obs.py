@@ -38,6 +38,7 @@ from repro.core.engine import (
     ENGINE_COUNTERS,
     FAULT_COUNTERS,
     REPAIR_COUNTERS,
+    SELECT_COUNTERS,
     VIEW_COUNTERS,
 )
 from repro.core.tpch import generate_simulation
@@ -168,6 +169,9 @@ class TestCounterCoverage:
         for counter in VIEW_COUNTERS:
             assert counter in cat, f"VIEW_COUNTERS[{counter!r}] not registered"
             assert counter in ENGINE_COUNTERS
+        for counter in SELECT_COUNTERS:
+            assert counter in cat, f"SELECT_COUNTERS[{counter!r}] not registered"
+            assert counter in ENGINE_COUNTERS
         # the stats view exposes every engine counter
         stats = eng.stats
         for name in ENGINE_COUNTERS:
@@ -184,6 +188,30 @@ class TestCounterCoverage:
             assert counter in FRONTDOOR_COUNTERS
         # exact public stats key set: the pre-registry dict, unchanged
         assert set(fd.stats) == set(FRONTDOOR_COUNTERS) | {"max_queue_depth"}
+
+    def test_select_counters_move_with_their_path(self):
+        """A select on a single sorted run compacts over its slab window
+        (``select_sparse_launches``); once a flush appends a write run,
+        device row order is no longer sorted and the compaction streams
+        every block (``select_full_launches``)."""
+        kc, vc, schema = generate_simulation(512, 3, seed=0)
+        eng = HREngine(n_nodes=6, result_cache=False)
+        eng.create_column_family(
+            _CF, kc, vc, replication_factor=3, layouts=LAYOUTS, schema=schema,
+            device_resident=True, memtable_rows=0,
+        )
+        q = Query({"k0": Eq(int(kc["k0"][0])), "k1": Eq(int(kc["k1"][0]))}, agg="select")
+
+        def counts():
+            return tuple(eng.stats[c] for c in SELECT_COUNTERS)
+
+        (r, _), = eng.read_many(_CF, [q])
+        assert r.rows_matched > 0
+        assert counts() == (1, 0)
+        eng.write(_CF, {c: v[:5] for c, v in kc.items()}, {c: v[:5] for c, v in vc.items()})
+        (r2, _), = eng.read_many(_CF, [q])
+        assert r2.rows_matched > r.rows_matched
+        assert counts() == (1, 1)
 
     def test_deadline_exceeded_increments_counter(self):
         eng, schema = _engine(256)

@@ -275,7 +275,9 @@ def test_property_slab_locate_matches_searchsorted(seed, n, bits_a, bits_b):
 def test_property_select_compaction_matches_numpy_indices(seed, n):
     """Property: device "select" emits exactly the numpy engine's row
     indices (same values, same ascending order), mixed into sum/count
-    batches, including after incremental device appends."""
+    batches, including after incremental device appends. A single sorted
+    run compacts over its slab windows alone (block-sparse launches);
+    appended runs stream every row block (full launches)."""
     from repro.core import KeySchema
 
     rng = np.random.default_rng(seed)
@@ -285,7 +287,8 @@ def test_property_select_compaction_matches_numpy_indices(seed, n):
     vc = {"m": rng.uniform(0, 1, n)}
     dev = SortedTable.from_columns(kc, vc, ("x", "y"), schema).place_on_device()
     host = SortedTable.from_columns(kc, vc, ("x", "y"), schema)
-    if rng.random() < 0.5:  # half the runs read after an appended write
+    appended = rng.random() < 0.5
+    if appended:  # half the runs read after an appended write
         m = int(rng.integers(1, 50))
         kc2 = {"x": rng.integers(0, 10, m), "y": rng.integers(0, 10, m)}
         vc2 = {"m": rng.uniform(0, 1, m)}
@@ -296,13 +299,18 @@ def test_property_select_compaction_matches_numpy_indices(seed, n):
         rng, dev.schema, ("x", "y"), 8, aggs=("select", "sum", "count"),
         value_col="m",
     )
-    for q, rd in zip(qs, table_execute_device_many(dev, qs)):
+    stats = {}
+    out = table_execute_device_many(dev, qs, select_stats=stats)
+    for q, rd in zip(qs, out):
         rh = host.execute(q)
         assert rd.rows_scanned == rh.rows_scanned
         assert rd.rows_matched == rh.rows_matched
         np.testing.assert_allclose(rd.value, rh.value, rtol=1e-5, atol=1e-5)
         if q.agg == "select":
             np.testing.assert_array_equal(rd.selected, rh.selected)
+    launched = any(q.agg == "select" and r.rows_matched for q, r in zip(qs, out))
+    moved = "full_launches" if appended else "sparse_launches"
+    assert stats == ({moved: 1} if launched else {})
 
 
 @settings(max_examples=25, deadline=None)
