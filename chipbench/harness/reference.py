@@ -8,6 +8,15 @@ filter scans every row. Sums are taken in float64 over the matched
 rows in row order, counts exactly, and selected rows are returned as
 their full contents (key columns, then value columns), sorted.
 
+Where the mix writes, the reference holds the loaded rows followed by
+the written rows that were acknowledged, in order, and each read sees
+exactly the rows below its own ``visible`` count: the loaded rows plus
+the written rows acknowledged before its batch went out
+(read-your-writes). :func:`fingerprint` and :func:`device_fingerprint`
+check that every replica holds exactly the reference's rows once the
+window is over, in its host table and in the arrays reads are served
+from.
+
 ``Reference.answers(..., control=True)`` is the control: the reference
 computed one step below the precision the configuration states: sums folded in bfloat16 (stated:
 float32) and selected rows with their values in float32 (stated: exact
@@ -95,10 +104,15 @@ class Reference:
         ]
         return sort_rows(np.stack(cols, axis=1))
 
-    def answers(self, mix, idx: np.ndarray, *, control: bool = False) -> list[Answer]:
-        """The reference's answer to each query ``mix[idx[j]]``; with
+    def answers(self, mix, idx: np.ndarray, *, control: bool = False,
+                visible: np.ndarray | None = None) -> list[Answer]:
+        """The reference's answer to each query ``mix[idx[j]]``, over
+        the rows below ``visible[j]`` (default: every row); with
         ``control``, the control's (see the module doc)."""
         pos, rows = self.matches(mix, idx)
+        if visible is not None:
+            seen = rows < np.asarray(visible, np.int64)[pos]
+            pos, rows = pos[seen], rows[seen]
         counts = np.bincount(pos, minlength=idx.size)
         bounds = np.concatenate([[0], np.cumsum(counts)])
         tmpl = mix.tmpl[idx]
@@ -128,6 +142,58 @@ class Reference:
         return out
 
 
+_ROUND = [np.uint64(m) for m in (0x9E3779B97F4A7C15, 0xBF58476D1CE4E5B9, 0x94D049BB133111EB,
+                                  0xD6E8FEB86659FD93, 0xC2B2AE3D27D4EB4F, 0x165667B19E3779F9,
+                                  0xFF51AFD7ED558CCD, 0xC4CEB9FE1A85EC53)]
+
+
+def _mix64(x: np.ndarray) -> np.ndarray:
+    """splitmix64's finalizer, lane-wise (uint64 arithmetic wraps)."""
+    x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    return x ^ (x >> np.uint64(31))
+
+
+def _digest(cols: list) -> tuple[int, int]:
+    """``(rows, digest)`` of the rows whose columns are the int64 arrays
+    ``cols``, independent of row order: the sum modulo 2**64 of one
+    64-bit hash per row. Each column folds into the row's hash by an
+    xor, an odd multiplier and a shift, and splitmix64's finalizer
+    mixes the result; two row sets that differ in a row differ in the
+    digest but for a chance of about 2**-64."""
+    n = cols[0].shape[0] if cols else 0
+    h = np.zeros(n, np.uint64)
+    with np.errstate(over="ignore"):
+        for k, c in enumerate(cols):
+            h ^= np.asarray(c, np.int64).view(np.uint64)
+            h *= _ROUND[k % len(_ROUND)]
+            h ^= h >> np.uint64(29)
+        return n, int(np.sum(_mix64(h), dtype=np.uint64))
+
+
+def fingerprint(key_cols: dict, value_cols: dict) -> tuple[int, int]:
+    """``(rows, digest)`` of a host row set over every key column and
+    every value column in hundredths (order keys, status and priorities
+    are whole, prices have two decimals, so each is an exact integer
+    there)."""
+    cols = [np.asarray(key_cols[c], np.int64) for c in sorted(key_cols)]
+    cols += [np.rint(np.asarray(value_cols[c], np.float64) * 100).astype(np.int64)
+             for c in sorted(value_cols)]
+    return _digest(cols)
+
+
+def device_fingerprint(key_cols: dict, value_cols: dict, ones=None) -> tuple[int, int]:
+    """``(rows, digest)`` of a row set as a device-resident replica
+    holds it: every key column whole, every value column as the bits of
+    its float32, and the count lane (``ones``: 1.0 on every row, which
+    is what the reference holds)."""
+    cols = [np.asarray(key_cols[c], np.int64) for c in sorted(key_cols)]
+    n = cols[0].shape[0] if cols else 0
+    f32 = [np.asarray(value_cols[c], np.float32) for c in sorted(value_cols)]
+    f32.append(np.ones(n, np.float32) if ones is None else np.asarray(ones, np.float32))
+    return _digest(cols + [v.view(np.int32).astype(np.int64) for v in f32])
+
+
 def _bf16_fold(values: np.ndarray) -> float:
     """Sequential sum in bfloat16 arithmetic."""
     bf16 = ml_dtypes.bfloat16
@@ -145,21 +211,32 @@ def sort_rows(rows: np.ndarray) -> np.ndarray:
 
 @dataclasses.dataclass
 class Comparison:
-    """The numbers compared, each with its limit."""
+    """The numbers compared, each with its limit. A mix that writes adds
+    two: ``writes_failed``, the written rows due in the window that were
+    not acknowledged (never sent, or their write raised), and
+    ``replica_mismatches``, the replicas whose rows after the window
+    differ from the reference's in number or content, in the host table
+    or in the device-resident arrays."""
 
     compared: int = 0
     failed: int = 0
     count_mismatches: int = 0
     row_mismatches: int = 0
     sum_rel_gap: float = 0.0
+    writes_failed: int | None = None
+    replica_mismatches: int | None = None
 
     def numbers(self, limits: dict) -> dict:
-        return {
+        out = {
             "failed": {"value": self.failed, "limit": 0},
             "count_mismatches": {"value": self.count_mismatches, "limit": 0},
             "row_mismatches": {"value": self.row_mismatches, "limit": 0},
-            "sum_rel_gap": {"value": self.sum_rel_gap, "limit": limits["sum_rel_gap"]},
         }
+        if self.writes_failed is not None:
+            out["writes_failed"] = {"value": self.writes_failed, "limit": 0}
+            out["replica_mismatches"] = {"value": self.replica_mismatches, "limit": 0}
+        out["sum_rel_gap"] = {"value": self.sum_rel_gap, "limit": limits["sum_rel_gap"]}
+        return out
 
     def correct(self, limits: dict) -> bool:
         return all(n["value"] <= n["limit"] for n in self.numbers(limits).values())

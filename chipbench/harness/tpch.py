@@ -11,6 +11,11 @@ order keys, the status an order's lines give it, a priority of five,
 and a ship priority of 0. This is the benchmark's own copy of the
 generator, so that a change to the program cannot change the data a
 cell runs on.
+
+:func:`new_orders` makes the rows of TPC-H's New Sales refresh
+function (RF1, specification 2.5.2), :func:`rf1_rows` of them a
+refresh: orders drawn as the loader draws them, under order keys that
+no loaded order holds.
 """
 
 from __future__ import annotations
@@ -34,6 +39,12 @@ def n_rows(scale_factor: float) -> int:
     return int(scale_factor * ROWS_PER_SF)
 
 
+def rf1_rows(loaded_rows: int) -> int:
+    """Orders one RF1 inserts (specification 2.5.2): SF x 1500 against
+    the SF x 1,500,000 orders loaded, a thousandth of the table."""
+    return max(1, loaded_rows // 1000)
+
+
 def domains(rows: int) -> dict[str, int]:
     """Number of distinct values of each clustering key at ``rows``."""
     return {
@@ -43,10 +54,13 @@ def domains(rows: int) -> dict[str, int]:
     }
 
 
-def order_keys(rows: int) -> np.ndarray:
-    """dbgen's sparse keys: of every 32 keys the first 8 are used."""
+def order_keys(rows: int, slot: int = 0) -> np.ndarray:
+    """dbgen's sparse keys (``mk_sparse``): order ``i`` (from 1) gets
+    ``(((i >> 3) << 2 | slot) << 3) | (i & 7)``, so of every 32 keys
+    the loader (slot 0) uses the first 8 and leaves slots 1-3 of each
+    32 for refresh data."""
     i = np.arange(1, rows + 1, dtype=np.int64)
-    return ((i >> 3) << 5 | (i & 7)).astype(np.float64)
+    return (((i >> 3) << 2 | slot) << 3 | (i & 7)).astype(np.float64)
 
 
 def order_status(orderdate: np.ndarray, rng) -> np.ndarray:
@@ -65,18 +79,30 @@ def order_status(orderdate: np.ndarray, rng) -> np.ndarray:
     )
 
 
-def generate_orders(rows: int, seed: int) -> tuple[dict, dict]:
-    """``(key_cols, value_cols)``: int64 keys uniform over their domains
-    and float64 values, all drawn in bulk from ``seed``."""
-    rng = np.random.default_rng(seed)
-    dom = domains(rows)
+def _draw_orders(rows: int, dom: dict[str, int], orderkey: np.ndarray, rng) -> tuple[dict, dict]:
     key_cols = {c: rng.integers(0, dom[c], rows, dtype=np.int64) for c in dom}
     lo, hi = TOTALPRICE_RANGE
     value_cols = {
-        "orderkey": order_keys(rows),
+        "orderkey": orderkey,
         "orderstatus": order_status(key_cols["orderdate"], rng),
         "totalprice": np.round(rng.uniform(lo, hi, rows), 2),
         "orderpriority": rng.integers(1, ORDER_PRIORITIES + 1, rows).astype(np.float64),
         "shippriority": np.full(rows, SHIPPRIORITY),
     }
     return key_cols, value_cols
+
+
+def generate_orders(rows: int, seed: int) -> tuple[dict, dict]:
+    """``(key_cols, value_cols)``: int64 keys uniform over their domains
+    and float64 values, all drawn in bulk from ``seed``."""
+    return _draw_orders(rows, domains(rows), order_keys(rows), np.random.default_rng(seed))
+
+
+def new_orders(loaded_rows: int, rows: int, seed) -> tuple[dict, dict]:
+    """The first ``rows`` orders of RF1 against a table loaded with
+    ``loaded_rows`` orders: keys and values drawn as the loader draws
+    them (over the loaded table's key domains), from ``seed``. Their
+    order keys are dbgen's sparse slot 1 (:func:`order_keys`), which no
+    loaded order holds, as RF1 requires."""
+    return _draw_orders(rows, domains(loaded_rows), order_keys(rows, slot=1),
+                        np.random.default_rng(seed))

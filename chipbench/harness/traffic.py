@@ -17,6 +17,15 @@ Open-loop arrivals are a Poisson process conditioned on its count: the
 for every seed, in an order drawn from the seed. So runs with different
 seeds do the same amount of work in the same window, and differ only in
 order and in the key values drawn.
+
+A mix may also carry a ``writes`` block: refreshes of ``generator``
+due at the times ``refresh_at_s`` on the window's clock, each all its
+rows at once (:func:`write_stream`), drawn on the seed stream
+``stream``; and every ``fresh_read_every``-th read aimed at a written
+row (:func:`aim_at_rows`), so that read-your-writes shows in the
+answers. Such a mix may not read with ``select``: a select's rows are
+read back through the replica tables captured before the window, which
+flushes replace.
 """
 
 from __future__ import annotations
@@ -85,6 +94,50 @@ def arrivals(rate_per_s: float, seconds: float, seed) -> np.ndarray:
     gaps = np.random.default_rng(seed).permutation(-np.log1p(-u))
     gaps *= seconds / gaps.sum()
     return np.cumsum(gaps)[:n]
+
+
+def check_mix(mix_cfg: dict) -> None:
+    """Refuse a mix the harness cannot check: writes beside selects."""
+    if "writes" in mix_cfg and any(t["agg"] == "select" for t in mix_cfg["templates"]):
+        raise ValueError(
+            f"traffic {mix_cfg['name']!r} mixes writes with select templates: select rows "
+            "are read back through tables captured before the window, which flushes replace"
+        )
+
+
+def write_stream(writes: dict, rows_per_refresh: int) -> tuple[np.ndarray, np.ndarray]:
+    """``(due_s, ends)`` of a mix's writes: ``rows_per_refresh`` rows due
+    at each of ``refresh_at_s``, in time order, and the end row
+    (exclusive) of each refresh."""
+    at = np.sort(np.asarray(writes["refresh_at_s"], float))
+    if at.size < 1 or rows_per_refresh < 1:
+        raise ValueError(f"writes {writes!r} insert no row")
+    return np.repeat(at, rows_per_refresh), np.arange(1, at.size + 1) * rows_per_refresh
+
+
+def aim_at_rows(mix: Mix, dom: dict[str, int], key_cols: dict, every: int, seed) -> np.ndarray:
+    """Aim every ``every``-th query of ``mix`` (in place) at a row of
+    ``key_cols`` drawn from ``seed``: each equality takes the row's
+    value, and each span keeps its size and moves to a start, drawn
+    from ``seed``, at which it covers the row's value and stays inside
+    the key's domain ``dom``. Returns the
+    aimed queries' indices. The work a query does is unchanged: the
+    same template, the same span sizes."""
+    rng = np.random.default_rng(seed)
+    at = np.arange(every - 1, len(mix), every)
+    row = rng.integers(0, len(next(iter(key_cols.values()))), at.size)
+    for t_idx, t in enumerate(mix.templates):
+        sel = at[mix.tmpl[at] == t_idx]
+        r = row[mix.tmpl[at] == t_idx]
+        for col, f in t["filters"].items():
+            v = np.asarray(key_cols[col], np.int64)[r]
+            if f.get("eq"):
+                mix.lo[col][sel], mix.hi[col][sel] = v, v + 1
+            elif "span" in f:
+                span = mix.hi[col][sel] - mix.lo[col][sel]
+                start = np.clip(v - rng.integers(0, span), 0, dom[col] - span)
+                mix.lo[col][sel], mix.hi[col][sel] = start, start + span
+    return at
 
 
 def to_queries(mix: Mix) -> list:

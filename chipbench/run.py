@@ -6,7 +6,10 @@
 are files under ``chipbench/`` found by name. The run builds the
 deployment from ``--seed`` on the chip, warms every shape it will use,
 then drives the program's front door from a single-threaded client for
-``--seconds`` and checks every answer against the plain reference.
+``--seconds`` and checks every answer against the plain reference. A
+mix that writes also inserts its rows through ``HREngine.write`` in the
+same loop; after the window every replica is flushed and checked to
+hold exactly the reference's rows.
 
 With ``--trace 0`` it reports the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace and the
@@ -24,6 +27,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 from contextlib import nullcontext  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -123,8 +127,10 @@ def program_answers(window, tables: dict) -> list:
 
 class Run:
     """What a per-layer reader may read: the window's reads, the
-    front door's counters, the program's span totals, the device trace,
-    the resident shapes and the chip's peaks."""
+    counters (the front door's under their own names, the engine's
+    ``stats`` over the window under ``engine.<name>``), the program's
+    span totals, the device trace, the resident shapes and the chip's
+    peaks."""
 
     def __init__(self, reads, counters, stages, trace, shapes, peaks):
         self.reads = reads
@@ -139,6 +145,17 @@ class Run:
         if not s or not s["count"] or not self.reads:
             return None
         return s["total"] * 1e6 / self.reads
+
+    def span_ms_per_call(self, name: str):
+        s = (self.stages or {}).get(name)
+        return s["total"] * 1e3 / s["count"] if s and s["count"] else None
+
+
+def engine_window_counters(before: dict, after: dict) -> dict:
+    """The engine's numeric ``stats`` over the window, as
+    ``engine.<name>``: what each counter gained."""
+    return {f"engine.{k}": after[k] - before.get(k, 0) for k, v in after.items()
+            if isinstance(v, (int, float))}
 
 
 def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
@@ -171,6 +188,7 @@ def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
     jax_events = JaxEvents()
 
     cfg, mix_cfg = cell.config, cell.traffic
+    traffic.check_mix(mix_cfg)
     seed = args.seed
     n_rows, kc, vc = deploy.make_data(cfg, seed, rows)
     dom = tpch.domains(n_rows)
@@ -187,9 +205,19 @@ def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
     else:
         n_reads = int(mix_cfg["pool"])
     mix = traffic.queries(templates, dom, n_reads, [seed, 1])
+    wcfg = mix_cfg.get("writes")
+    if wcfg is not None:
+        write_due, ends = traffic.write_stream(wcfg, tpch.rf1_rows(n_rows))
+        wk, wv = deploy.make_writes(wcfg, n_rows, write_due.size, seed)
+        # reads aimed at written rows see them only by read-your-writes
+        aimed = np.zeros(len(mix), bool)
+        aimed[traffic.aim_at_rows(mix, dom, wk, int(wcfg["fresh_read_every"]),
+                                  [seed, wcfg["stream"], 2])] = True
     queries = traffic.to_queries(mix)
 
     warmup.warm(eng, cf, templates, dom, max_batch, timeout_s, seed)
+    if wcfg is not None:
+        warmup.warm_writes(eng, cf, ends, max_batch)
 
     tracer = None
     if args.trace:
@@ -197,6 +225,9 @@ def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
 
         tracer = Tracer()
     fd = client.make_frontdoor(eng, max_batch, tracer=tracer)
+    writes = None
+    if wcfg is not None:
+        writes = client.Writes(eng, cf, wk, wv, write_due, tracer=tracer)
     client.settle()
     setup_s = time.perf_counter() - t_start
 
@@ -205,20 +236,33 @@ def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
         if args.trace:
             jax.profiler.start_trace(trace_dir, profiler_options=devtrace.profiler_options())
         window = jax.profiler.TraceAnnotation(devtrace.WINDOW) if args.trace else nullcontext()
+        stats_before = eng.stats
         t_window = time.perf_counter()
         with window, client.GcPauses() as gcp:
             if mix_cfg["loop"] == "open":
                 win = client.open_loop(fd, cf, queries, due, max_batch=max_batch,
-                                       timeout_s=timeout_s, trace=bool(args.trace))
+                                       timeout_s=timeout_s, trace=bool(args.trace),
+                                       writes=writes)
             else:
                 win = client.closed_loop(fd, cf, queries, clients=int(mix_cfg["clients"]),
                                          seconds=args.seconds, timeout_s=timeout_s,
-                                         trace=bool(args.trace))
+                                         trace=bool(args.trace), writes=writes)
         if args.trace:
             jax.profiler.stop_trace()
         window_events = jax_events.between(t_window, time.perf_counter())
+        engine_counters = engine_window_counters(stats_before, eng.stats)
         mem = dev.memory_stats() or {}
         memory_peak = int(mem.get("peak_bytes_in_use", 0))
+        if writes is not None:
+            # every acknowledged write on every replica: flush what is
+            # still staged, then read each replica's rows back, from its
+            # host table and from the resident arrays reads are served from
+            eng.flush_memtables(cf)
+            replica_prints = {
+                rid: (reference.fingerprint(t.key_cols, t.value_cols),
+                      reference.device_fingerprint(*deploy.device_rows(t)))
+                for rid, t in deploy.replica_tables(eng, cf).items()
+            }
 
         dtrace = devtrace.load(trace_dir, dev.platform.upper()) if args.trace else None
     finally:
@@ -227,13 +271,17 @@ def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
 
     got = program_answers(win, tables)
     counters = dict(fd.stats)
+    counters.update(engine_counters)
     stages = None
     if tracer is not None:
         from repro.obs import stage_totals
 
         stages = stage_totals(tracer.roots)
         tracer.clear()
-    del eng, fd, tables, tracer
+    if writes is not None:
+        write_lat, write_failed = writes.latency_s, writes.failed | np.isnan(writes.latency_s)
+        write_calls, write_errors = writes.calls, writes.errors
+    del eng, fd, tables, tracer, writes
     gc.unfreeze()
     gc.collect()
 
@@ -241,20 +289,34 @@ def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
     t_check = time.perf_counter()
     n = win.n
     idx = np.arange(n) % len(mix)
+    visible = None
+    if wcfg is not None:
+        # the loaded rows, then the acknowledged writes in order; a read
+        # sees the loaded rows and the writes acknowledged before its batch
+        acked = ~write_failed
+        kc = {c: np.concatenate([kc[c], wk[c][acked]]) for c in kc}
+        vc = {c: np.concatenate([vc[c], wv[c][acked]]) for c in vc}
+        visible = n_rows + np.concatenate([[0], np.cumsum(acked)])[win.watermark]
     ref = reference.Reference(kc, vc, dom)
-    want = ref.answers(mix, idx)
+    want = ref.answers(mix, idx, visible=visible)
     aggs = [mix.agg(i) for i in idx]
     missing = win.status == client.MISSING
     refused = win.status == client.REFUSED
     cmp = reference.compare(got, want, aggs)
+    if wcfg is not None:
+        ref_print = (reference.fingerprint(kc, vc), reference.device_fingerprint(kc, vc))
+        cmp.writes_failed = int(np.sum(write_failed))
+        cmp.replica_mismatches = sum(p != ref_print for p in replica_prints.values())
     program_cmp = None
     if control:
         # the control puts the reference one precision step down in the
         # program's place, for every read the program answered
         program_cmp = cmp
-        low = ref.answers(mix, idx, control=True)
+        low = ref.answers(mix, idx, control=True, visible=visible)
         got = [None if g is None else w for g, w in zip(got, low)]
-        cmp = reference.compare(got, want, aggs)
+        cmp = dataclasses.replace(reference.compare(got, want, aggs),
+                                  writes_failed=program_cmp.writes_failed,
+                                  replica_mismatches=program_cmp.replica_mismatches)
     check_s = time.perf_counter() - t_check
     limits = cfg["limits"]
     correct = cmp.correct(limits)
@@ -285,8 +347,12 @@ def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
 
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(devs),
               "memory_peak_bytes": memory_peak}
-    result = {"correct": bool(correct), "attempted": int(n),
-              "failed": int(np.sum(fail_mask)), "metrics": result_metrics,
+    attempted, failed = int(n), int(np.sum(fail_mask))
+    if wcfg is not None:
+        attempted += int(write_lat.size)
+        failed += int(np.sum(write_failed))
+    result = {"correct": bool(correct), "attempted": attempted,
+              "failed": failed, "metrics": result_metrics,
               "device": device}
     if dtrace is not None:
         device["busy_s"] = dtrace.busy_s
@@ -304,6 +370,19 @@ def main(argv=None, *, chip_required: bool = True, rows: int | None = None,
     print(f"chipbench: generator lateness p50 {np.median(late) * 1e6 if late.size else 0:.1f} us, "
           f"max {late.max() * 1e6 if late.size else 0:.1f} us over {late.size} waits; "
           f"JAX compile events in window {window_events or 'none'}; {gcp.summary()}", file=sys.stderr)
+    if wcfg is not None:
+        ok_lat = write_lat[~write_failed]
+        rows = {r: f"{h[0]}/{d[0]}{'' if (h, d) == ref_print else ' differs'}"
+                for r, (h, d) in replica_prints.items()}
+        print(f"chipbench: writes: {write_lat.size} rows due in {write_calls} calls, "
+              f"{int(np.sum(write_failed))} failed {write_errors[:3]}; latency p50 "
+              f"{np.median(ok_lat) * 1e3 if ok_lat.size else float('nan'):.1f} ms, max "
+              f"{ok_lat.max() * 1e3 if ok_lat.size else float('nan'):.1f} ms; in the window "
+              f"{engine_counters.get('engine.memtable_flushes', 0)} flushes, "
+              f"{engine_counters.get('engine.compactions', 0)} compactions; "
+              f"{int(np.sum(aimed[idx] & (win.watermark > 0)))} reads aimed at written rows "
+              f"after a write; replica rows host/device {rows} "
+              f"against {ref_print[0][0]}", file=sys.stderr)
     for start, secs, size in win.slowest(5):
         t0 = win.t0 + start
         print(f"chipbench: slow batch at {start:.3f} s: {size} reads, {secs * 1e3:.1f} ms, "

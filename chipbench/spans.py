@@ -13,9 +13,13 @@ standard error and as one more JSON line on standard output:
   own time from the annotations beside ``frontdoor.self_us_per_read``'s
   reading; ``device.idle_in_engine_pct`` from the annotations beside its
   reading;
+- per call, each write-path stage the window ran (:data:`WRITE_PATH`):
+  the write itself, and the flush a read barrier or a write makes, with
+  its host merge, view extension and compaction;
 - for each of the ten longest idle gaps of chip 0, the chain of program
   spans that covers its middle, innermost first, or ``front door`` where
-  only ``client.serve`` covers it.
+  only ``client.serve`` covers it. A gap inside a flush is named by the
+  flush's spans.
 """
 
 from __future__ import annotations
@@ -30,6 +34,9 @@ if str(BENCH_DIR) not in sys.path:
 
 import run  # noqa: E402
 from harness import devtrace, program_trace  # noqa: E402
+
+WRITE_PATH = ("engine.write", "engine.flush_barrier", "engine.flush", "engine.flush_merge",
+              "view.build", "engine.compaction")
 
 
 def traced_run(argv, **kw):
@@ -67,6 +74,11 @@ def report(result: dict, r, program) -> dict:
         for name, s in stages.items()
         if name.startswith(program_trace.PROGRAM) and not name.startswith("frontdoor.")
     }
+    write_path = {
+        name: {"count": stages[name]["count"], "total_s": stages[name]["total"],
+               "ms_per_call": stages[name]["total"] * 1e3 / stages[name]["count"]}
+        for name in WRITE_PATH if stages.get(name, {}).get("count")
+    }
     front_door_us = program_trace.covered_s(serve, rm) * 1e6 / reads
     idle_in_engine = 100.0 * program_trace.idle_in_s(t, rm) / t.window_s
     gaps = [
@@ -85,6 +97,7 @@ def report(result: dict, r, program) -> dict:
             "annotations": idle_in_engine,
             "reader": _reading(metrics, "device.idle_in_engine_pct."),
         },
+        "write_path": write_path,
         "idle_gaps": gaps,
     }
     gap = (ann_s - span_s) / span_s * 100 if span_s else float("nan")
@@ -95,6 +108,9 @@ def report(result: dict, r, program) -> dict:
     say(f"engine.read_many {span_s:.6f} s by its spans, {ann_s:.6f} s by its "
         f"profiler annotations ({gap:+.3f} %)")
     say("us per read: " + ", ".join(f"{k} {v:.3f}" for k, v in per_read.items()))
+    if write_path:
+        say("write path, ms per call: " + ", ".join(
+            f"{k} {v['ms_per_call']:.3f} x {v['count']}" for k, v in write_path.items()))
     say(f"front door {front_door_us:.3f} us per read by the annotations, reader "
         f"{out['frontdoor.self_us_per_read']['reader']}; idle in engine {idle_in_engine:.4f} % "
         f"by the annotations, reader {out['device.idle_in_engine_pct']['reader']}")

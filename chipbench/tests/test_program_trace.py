@@ -142,6 +142,8 @@ def root(tmp_path_factory):
     shutil.copy(spec.BENCH_DIR / "peaks.json", root / "chipbench")
     f = root / "chipbench" / "traffic" / "q1q2-closed64.json"
     f.write_text(json.dumps(dict(json.loads(f.read_text()), pool=256)))
+    f = root / "chipbench" / "traffic" / "q1q2-newsales-closed64.json"
+    f.write_text(json.dumps(dict(json.loads(f.read_text()), pool=256)))
     return root
 
 
@@ -161,3 +163,20 @@ def test_spans_report_on_a_small_traced_run(root, capsys):
     assert 0 < idle["reader"] <= result["metrics"]["device.idle_pct.closed"]["value"]
     _out, err = capsys.readouterr()
     assert "engine.read_many" in err and json.loads(_out.strip().splitlines()[-1]) == {"spans": out}
+
+
+def test_spans_report_the_write_path(root, capsys):
+    argv = ["--workload", "orders-hr.q1q2.newsales.closed64", "--seed", str(2**31 + 6),
+            "--seconds", "1"]
+    result, r, program = spans.traced_run(argv, chip_required=False, rows=20_000, root=root)
+    assert result["correct"]
+    out = spans.report(result, r, program)
+    wp = out["write_path"]
+    assert wp["engine.write"]["count"] == 1
+    for name in ("engine.flush_barrier", "engine.flush", "engine.flush_merge", "view.build"):
+        assert wp[name]["count"] > 0 and wp[name]["ms_per_call"] > 0
+    assert wp["engine.flush_merge"]["count"] == wp["engine.flush"]["count"]
+    # the write and flush spans reach the profiler's clock too
+    assert program_trace.intervals(program, "engine.flush_merge")
+    _out, err = capsys.readouterr()
+    assert "write path, ms per call: engine.write" in err

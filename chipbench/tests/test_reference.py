@@ -72,3 +72,47 @@ def test_compare_counts_each_kind_of_fault():
     refused = reference.compare([None], w[:1], aggs[:1])
     assert refused.failed == 1 and refused.compared == 0
     assert not refused.correct({"sum_rel_gap": 1.0})
+
+
+def test_a_read_sees_only_the_rows_below_its_visible_count(data):
+    kc, vc, dom, ref = data
+    templates = spec.load_json(spec.traffic_file("q1q2-hr-r80"))["templates"]
+    mix = traffic.queries(templates, dom, 200, [4, 1])
+    idx = np.arange(len(mix))
+    visible = np.random.default_rng(3).integers(0, ROWS + 1, idx.size)
+    got = ref.answers(mix, idx, visible=visible)
+    for i, a in enumerate(got):
+        rows = brute(kc, vc, mix, i)
+        rows = rows[rows < visible[i]]
+        assert a.matched == rows.size
+        assert a.value == pytest.approx(float(np.sum(vc["totalprice"][rows])), rel=1e-12, abs=1e-9)
+    everything = ref.answers(mix, idx, visible=np.full(idx.size, ROWS))
+    assert [a.value for a in everything] == [a.value for a in ref.answers(mix, idx)]
+
+
+def test_fingerprint_ignores_row_order_and_sees_any_changed_row(data):
+    kc, vc, _dom, _ref = data
+    n, digest = reference.fingerprint(kc, vc)
+    order = np.random.default_rng(1).permutation(ROWS)
+    assert reference.fingerprint({c: v[order] for c, v in kc.items()},
+                                 {c: v[order] for c, v in vc.items()}) == (n, digest)
+    assert n == ROWS
+    cents = dict(vc, totalprice=vc["totalprice"] + np.where(np.arange(ROWS) == 5, 0.01, 0.0))
+    assert reference.fingerprint(kc, cents)[1] != digest
+    fewer = reference.fingerprint({c: v[1:] for c, v in kc.items()}, {c: v[1:] for c, v in vc.items()})
+    assert fewer[0] == ROWS - 1 and fewer[1] != digest
+
+
+def test_device_fingerprint_reads_values_as_float32_and_the_count_lane(data):
+    kc, vc, _dom, _ref = data
+    n, digest = reference.device_fingerprint(kc, vc)
+    f32 = {c: np.asarray(v, np.float32) for c, v in vc.items()}
+    assert reference.device_fingerprint(kc, f32, np.ones(ROWS, np.float32)) == (n, digest)
+    order = np.random.default_rng(2).permutation(ROWS)
+    assert reference.device_fingerprint({c: v[order] for c, v in kc.items()},
+                                        {c: v[order] for c, v in f32.items()}) == (n, digest)
+    ones = np.ones(ROWS, np.float32)
+    ones[7] = 0.0
+    assert reference.device_fingerprint(kc, f32, ones)[1] != digest
+    bumped = dict(f32, totalprice=np.nextafter(f32["totalprice"], np.float32(np.inf)))
+    assert reference.device_fingerprint(kc, bumped)[1] != digest

@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 
 from harness import spec, tpch, traffic
 
@@ -51,3 +52,45 @@ def test_program_queries_carry_the_filters():
         for c, f in mix.templates[mix.tmpl[i]]["filters"].items():
             assert q.filter_bounds(None, c) == (mix.lo[c][i], mix.hi[c][i])
             assert q.is_equality_on(c) == bool(f.get("eq"))
+
+
+def test_new_orders_hold_keys_no_loaded_order_holds():
+    loaded = 40_000
+    kc, vc = tpch.new_orders(loaded, 3000, [7, 5, 1])
+    again, _ = tpch.new_orders(loaded, 3000, [7, 5, 1])
+    assert all(np.array_equal(kc[c], again[c]) for c in kc)
+    assert np.intersect1d(vc["orderkey"], tpch.order_keys(loaded)).size == 0
+    assert np.unique(vc["orderkey"]).size == 3000
+    dom = tpch.domains(loaded)
+    assert all(0 <= kc[c].min() and kc[c].max() < dom[c] for c in dom)
+    base_k, base_v = tpch.generate_orders(loaded, 7)
+    assert set(kc) == set(base_k) and set(vc) == set(base_v)
+
+
+def test_each_refresh_is_due_whole_at_its_time():
+    due, ends = traffic.write_stream({"refresh_at_s": [5.0, 0.0]}, tpch.rf1_rows(7_500_000))
+    assert tpch.rf1_rows(7_500_000) == 7500 and ends.tolist() == [7500, 15000]
+    assert due[:7500].tolist() == [0.0] * 7500 and due[7500:].tolist() == [5.0] * 7500
+    with pytest.raises(ValueError):
+        traffic.write_stream({"refresh_at_s": []}, 10)
+
+
+def test_aimed_queries_cover_their_row_and_keep_their_work():
+    templates = spec.load_json(spec.traffic_file("q1q2-newsales-closed64"))["templates"]
+    dom = tpch.domains(40_000)
+    mix = traffic.queries(templates, dom, 400, [3, 1])
+    plain = traffic.queries(templates, dom, 400, [3, 1])
+    kc, _vc = tpch.new_orders(40_000, 40, [3, 5, 1])
+    at = traffic.aim_at_rows(mix, dom, kc, 8, [3, 5, 2])
+    assert at.tolist() == list(range(7, 400, 8))
+    untouched = np.setdiff1d(np.arange(400), at)
+    for c in mix.lo:
+        assert np.array_equal(mix.lo[c][untouched], plain.lo[c][untouched])
+        # the same span sizes, inside the domain
+        assert np.array_equal(mix.hi[c] - mix.lo[c], plain.hi[c] - plain.lo[c])
+        assert mix.lo[c].min() >= 0 and mix.hi[c].max() <= dom[c]
+    rows = np.stack([kc[c] for c in sorted(mix.lo)], axis=1)
+    for i in at:
+        lo = np.array([mix.lo[c][i] for c in sorted(mix.lo)])
+        hi = np.array([mix.hi[c][i] for c in sorted(mix.lo)])
+        assert np.any(np.all((rows >= lo) & (rows < hi), axis=1))
